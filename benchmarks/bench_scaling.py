@@ -175,13 +175,13 @@ def measure_scale(rng, C: int, n: int, batch: int, repeats: int,
             t0 = time.perf_counter()
             h = srv.stage1_hits(sks)
             s1.append(time.perf_counter() - t0)
-        _, n0 = ex.stage_stats()
+        n0 = ex.stage_stats()
         e2e = []
         for _ in range(repeats):
             t0 = time.perf_counter()
             srv.query_batch(sks, request=req)
             e2e.append(time.perf_counter() - t0)
-        _, n1 = ex.stage_stats()
+        n1 = ex.stage_stats()
         assert srv.cache.misses == misses, f"compile after warmup ({cand})"
         hits[cand] = h
         delta = {k: n1.get(k, 0) - n0.get(k, 0)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sketch import PAD_KEY
+from repro.engine import trace as TR
 from repro.engine.index import Postings, placement
 from repro.engine.plans import _postings_window_candidates
 from repro.kernels import ops as K
@@ -106,7 +107,8 @@ class ScanSource:
         out = ex.probe_fn(B, emit_tables=emit)(*qa, ex.shard,
                                                *ex._prep_args(B))
         hits = out[0] if isinstance(out, tuple) else out
-        return np.asarray(jax.block_until_ready(hits))
+        with TR.span("serve.wait"):
+            return np.asarray(jax.block_until_ready(hits))
 
 
 def make_postings_probe_fn(E: int, W: int, batch: int, n: int,
@@ -196,7 +198,8 @@ class InvertedSource:
                 self._probe_fn(B, W)(qk, qm, self._keys_d, self._cols_d))
 
     def hit_counts(self, qa, B: int) -> np.ndarray:
-        cols, counts = jax.block_until_ready(
-            self._probe_fn(B, self.W)(qa[0], qa[2], self._keys_d,
-                                      self._cols_d))
-        return dense_hit_counts(np.asarray(cols), np.asarray(counts), self.C)
+        out = self._probe_fn(B, self.W)(qa[0], qa[2], self._keys_d,
+                                        self._cols_d)
+        with TR.span("serve.wait"):
+            cols, counts = (np.asarray(a) for a in jax.block_until_ready(out))
+        return dense_hit_counts(cols, counts, self.C)

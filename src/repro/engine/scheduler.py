@@ -49,6 +49,7 @@ import jax
 import numpy as np
 
 from repro.engine import plans as PL
+from repro.engine import trace as TR
 
 
 class QueryTicket:
@@ -57,13 +58,16 @@ class QueryTicket:
     ``result()`` blocks until the worker pool serves the ticket and
     returns the usual ``(scores, gids, r, m)`` numpy tuple (rows = this
     ticket's queries, ``k`` = this ticket's request.k), re-raising any
-    worker-side exception. Arrival/completion times are monotonic-clock
-    seconds; ``latency_s``/``missed_deadline`` are available after
-    completion.
+    worker-side exception. Its stamps are monotonic-clock seconds:
+    ``t_submit`` (queued), ``t_admit`` (popped into a dispatch group, the
+    admission numbered ``batch``), ``t_served`` (`Server.query_batch`
+    returned) and ``t_done`` (result set); ``latency_s``/
+    ``missed_deadline`` are available after completion.
     """
 
     __slots__ = ("sketches", "request", "nq", "seq", "t_submit", "deadline",
-                 "t_done", "_event", "_result", "_error")
+                 "t_admit", "batch", "t_served", "t_done", "_event",
+                 "_result", "_error")
 
     def __init__(self, sketches, request: PL.Request, nq: int, seq: int,
                  t_submit: float, deadline: Optional[float]):
@@ -73,6 +77,9 @@ class QueryTicket:
         self.seq = seq
         self.t_submit = t_submit
         self.deadline = deadline
+        self.t_admit: Optional[float] = None
+        self.batch: Optional[int] = None
+        self.t_served: Optional[float] = None
         self.t_done: Optional[float] = None
         self._event = threading.Event()
         self._result = None
@@ -279,6 +286,8 @@ class AsyncScheduler:
                     shrunk = True
         for t in group:
             q.popleft()
+            t.t_admit = now
+            t.batch = self._batches
         if not q:
             del self._pending[key]
         self._pending_n -= total
@@ -299,20 +308,28 @@ class AsyncScheduler:
                     self._work.wait()
                 if not self._pending:     # closed + drained
                     return
-                group, _ = self._take_locked(time.monotonic())
-            self._execute(group)
+                with TR.span("sched.admit"):
+                    group, _ = self._take_locked(time.monotonic())
+            # the dispatch's spans carry its head ticket and width
+            with TR.span("sched.batch", seq=group[0].seq, width=len(group)):
+                self._execute(group)
 
     def _execute(self, group: List[QueryTicket]) -> None:
         try:
             k_rep = max(t.request.k for t in group)
             rep = dataclasses.replace(group[0].request, k=k_rep)
-            sks = _merge_sketches(group)
+            with TR.span("sched.merge"):
+                sks = _merge_sketches(group)
             out = self.server.query_batch(sks, request=rep,
                                           refresh=self.refresh)
+            served_at = time.monotonic()
+            for t in group:
+                t.t_served = served_at
             # one device→host transfer per dispatch; the per-ticket row/k
             # slices below are then numpy views (an eager jax slice would
             # compile per distinct (nq, k) shape)
-            out_np = tuple(np.asarray(a) for a in out)
+            with TR.span("serve.wait"):
+                out_np = tuple(np.asarray(a) for a in out)
             now = time.monotonic()
             misses = served = 0
             s = 0

@@ -58,6 +58,7 @@ from repro.core.sketch import PAD_KEY, Agg, CorrelationSketch, build_sketch, mer
 from repro.engine import candidates as CD
 from repro.engine import plans as PL
 from repro.engine import query as Q
+from repro.engine import trace as TR
 from repro.engine.index import (IndexShard, KeyMinima, SketchIndex,
                                 build_postings, key_minima, place_shard,
                                 precompute_prep, query_arrays,
@@ -78,47 +79,58 @@ def build_query_sketches(keys_list: Sequence[np.ndarray],
     ``[NQ]`` axis, ready for `repro.engine.index.query_arrays`.
     """
     assert len(keys_list) == len(values_list) and keys_list, "empty query batch"
+    with TR.span("front.sketch"):
+        return _build_query_sketches(keys_list, values_list, n, agg, chunk)
+
+
+def _build_query_sketches(keys_list, values_list, n, agg, chunk):
     nq = len(keys_list)
     # ragged layout: only real chunks are materialised and sketched, so one
     # long query costs its own chunks, not nq × its chunk count. (The fold
     # below still runs max-chunk-count rounds over all nq rows, but each
     # round is an n-sized merge — noise next to the chunk-sized builds.)
-    counts = [max(1, -(-len(k) // chunk)) for k in keys_list]
-    starts = np.cumsum([0] + counts)
-    total = int(starts[-1])
-    keys = np.zeros((total, chunk), np.uint32)
-    vals = np.zeros((total, chunk), np.float32)
-    valid = np.zeros((total, chunk), bool)
-    offs = np.zeros((total,), np.float32)
-    for i, (k, v) in enumerate(zip(keys_list, values_list)):
-        m = len(k)
-        s = starts[i]
-        flat_k = np.zeros(counts[i] * chunk, np.uint32)
-        flat_v = np.zeros(counts[i] * chunk, np.float32)
-        flat_k[:m] = np.asarray(k, np.uint32)
-        flat_v[:m] = np.asarray(v, np.float32)
-        keys[s:s + counts[i]] = flat_k.reshape(counts[i], chunk)
-        vals[s:s + counts[i]] = flat_v.reshape(counts[i], chunk)
-        valid[s:s + counts[i]] = (np.arange(counts[i] * chunk) < m).reshape(
-            counts[i], chunk)
-        offs[s:s + counts[i]] = np.arange(counts[i], dtype=np.float32) * chunk
+    with TR.span("front.layout"):
+        counts = [max(1, -(-len(k) // chunk)) for k in keys_list]
+        starts = np.cumsum([0] + counts)
+        total = int(starts[-1])
+        keys = np.zeros((total, chunk), np.uint32)
+        vals = np.zeros((total, chunk), np.float32)
+        valid = np.zeros((total, chunk), bool)
+        offs = np.zeros((total,), np.float32)
+        for i, (k, v) in enumerate(zip(keys_list, values_list)):
+            m = len(k)
+            s = starts[i]
+            flat_k = np.zeros(counts[i] * chunk, np.uint32)
+            flat_v = np.zeros(counts[i] * chunk, np.float32)
+            flat_k[:m] = np.asarray(k, np.uint32)
+            flat_v[:m] = np.asarray(v, np.float32)
+            keys[s:s + counts[i]] = flat_k.reshape(counts[i], chunk)
+            vals[s:s + counts[i]] = flat_v.reshape(counts[i], chunk)
+            valid[s:s + counts[i]] = (np.arange(counts[i] * chunk) < m
+                                      ).reshape(counts[i], chunk)
+            offs[s:s + counts[i]] = np.arange(counts[i],
+                                              dtype=np.float32) * chunk
 
-    build = jax.vmap(lambda k, v, ok, off: build_sketch(
-        k, v, n=n, agg=agg, valid=ok, order_offset=off))
-    parts = build(jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(valid),
-                  jnp.asarray(offs))
+    with TR.span("front.chunks"):
+        build = jax.vmap(lambda k, v, ok, off: build_sketch(
+            k, v, n=n, agg=agg, valid=ok, order_offset=off))
+        parts = build(jnp.asarray(keys), jnp.asarray(vals),
+                      jnp.asarray(valid), jnp.asarray(offs))
 
     # fold round j merges chunk j into every query that still has one;
     # exhausted queries keep their fold result via the per-row select
-    out = jax.tree.map(lambda a: a[jnp.asarray(starts[:-1])], parts)
-    for j in range(1, max(counts)):
-        sel = np.array([starts[i] + j if counts[i] > j else 0 for i in range(nq)])
-        has = jnp.asarray(np.array([counts[i] > j for i in range(nq)]))
-        nxt = jax.tree.map(lambda a: a[jnp.asarray(sel)], parts)
-        merged = jax.vmap(merge)(out, nxt)
-        out = jax.tree.map(
-            lambda m_, o: jnp.where(has.reshape((nq,) + (1,) * (o.ndim - 1)), m_, o),
-            merged, out)
+    with TR.span("front.fold"):
+        out = jax.tree.map(lambda a: a[jnp.asarray(starts[:-1])], parts)
+        for j in range(1, max(counts)):
+            sel = np.array([starts[i] + j if counts[i] > j else 0
+                            for i in range(nq)])
+            has = jnp.asarray(np.array([counts[i] > j for i in range(nq)]))
+            nxt = jax.tree.map(lambda a: a[jnp.asarray(sel)], parts)
+            merged = jax.vmap(merge)(out, nxt)
+            out = jax.tree.map(
+                lambda m_, o: jnp.where(
+                    has.reshape((nq,) + (1,) * (o.ndim - 1)), m_, o),
+                merged, out)
     return out
 
 
@@ -219,13 +231,15 @@ JOIN_METRICS = ("containment", "jaccard", "join_size", "hits")
 #: concurrent program launches (DESIGN.md §10).
 _MESH_DISPATCH_LOCK = threading.RLock()
 
-#: per-stage telemetry vocabulary (DESIGN.md §11). Device-launch stages:
-#: "stage1" (probe / source hit counts), "stage2" (pruned scoring), "scan"
-#: (full scan — direct or fallback), "topm" (fused top-M plan), "fused"
-#: (the single-dispatch inverted safe plan). Host stages: "select" (host
+#: per-stage telemetry vocabulary (DESIGN.md §11): each stage is counted
+#: and is the span ``serve.<stage>``. Device-launch stages: "stage1" (probe
+#: / source hit counts), "stage2" (pruned scoring), "scan" (full scan —
+#: direct or fallback), "topm" (fused top-M plan), "fused" (the
+#: single-dispatch inverted safe plan). Host stages: "select" (host
 #: survivor selection + rung choice), "combine" (`combine_local_topk`).
 _DEVICE_STAGES = ("stage1", "stage2", "scan", "topm", "fused")
 _STAGE_NAMES = _DEVICE_STAGES + ("select", "combine")
+_STAGE_SPANS = {name: f"serve.{name}" for name in _STAGE_NAMES}
 
 
 class _SegmentExec:
@@ -305,12 +319,10 @@ class _SegmentExec:
         self._total_queries = 0
         self._total_dispatches = 0
         self._total_s = 0.0
-        #: per-stage serving telemetry (DESIGN.md §11): wall seconds and
-        #: invocation counts keyed by stage name ("stage1", "select",
-        #: "stage2", "scan", "topm", "fused", "combine"). Same `_tel_lock`
-        #: discipline as the dispatch log; stage windows may nest (the
-        #: "combine" host merge runs inside its enclosing dispatch stage)
-        self._stage_s: Dict[str, float] = {}
+        #: per-stage invocation counts keyed by stage name ("stage1",
+        #: "select", "stage2", "scan", "topm", "fused", "combine"; DESIGN.md
+        #: §11), under `_tel_lock` like the dispatch log. Their times are
+        #: the spans of the same names (`repro.engine.trace`)
         self._stage_n: Dict[str, int] = {}
         #: fused inverted-safe dispatch (DESIGN.md §11): last sufficient
         #: survivor rung (adapted per dispatch, guarded by ``_res_lock``)
@@ -630,26 +642,25 @@ class _SegmentExec:
         return list(_plan_cover(nq, self.buckets, costs))
 
     # -- dispatch ------------------------------------------------------------
-    def _stage(self, name: str, dt: float, n: int = 1) -> None:
-        """Accumulate one per-stage telemetry sample (wall seconds + count)
-        under ``_tel_lock`` — the PR 8 discipline: a racy ``+=`` under
-        concurrent dispatches silently loses updates."""
+    def _stage(self, name: str):
+        """Count one invocation of stage ``name`` under ``_tel_lock`` (a
+        racy ``+=`` under concurrent dispatches silently loses updates) and
+        return its span, ``with self._stage("fused"): ...``."""
         with self._tel_lock:
-            self._stage_s[name] = self._stage_s.get(name, 0.0) + dt
-            self._stage_n[name] = self._stage_n.get(name, 0) + n
+            self._stage_n[name] = self._stage_n.get(name, 0) + 1
+        return TR.span(_STAGE_SPANS[name])
 
     def _finish_ranked(self, out):
         """Block on a rank-stage output and, under the host combine, merge
         the concatenated per-device local top-ks ``[.., D·kk]`` into the
         global ``[.., k_max]`` (`plans.combine_local_topk`) — the only
         cross-shard step of a host-combine dispatch."""
-        out = jax.block_until_ready(out)
-        if self._host_combine:
-            t0 = time.perf_counter()
-            res = PL.combine_local_topk(*out, self.k_max)
-            self._stage("combine", time.perf_counter() - t0)
-            return res
-        return tuple(np.asarray(o) for o in out)
+        with TR.span("serve.wait"):
+            out = jax.block_until_ready(out)
+            if not self._host_combine:
+                return tuple(np.asarray(o) for o in out)
+        with self._stage("combine"):
+            return PL.combine_local_topk(*out, self.k_max)
 
     def _launch_lock(self):
         """`_MESH_DISPATCH_LOCK` on a sharded mesh, a no-op otherwise."""
@@ -680,19 +691,17 @@ class _SegmentExec:
                 out = self._dispatch_topm_sourced(qa, nq, B, prep_args, req,
                                                   ops)
             else:
-                ts = time.perf_counter()
-                out = self.topm_fn(B)(*qa, self.shard, *prep_args, ops)
-                s, g, r, m = self._finish_ranked(out)
-                self._stage("topm", time.perf_counter() - ts)
+                with self._stage("topm"):
+                    out = self.topm_fn(B)(*qa, self.shard, *prep_args, ops)
+                    s, g, r, m = self._finish_ranked(out)
                 g = np.where(np.isfinite(s), g, -1).astype(np.int32)
                 out = (s, g, r, m)
         elif req.prune == "safe":
             out = self._dispatch_safe(qa, nq, B, prep_args, req, ops)
         else:
-            ts = time.perf_counter()
-            out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-            out = self._finish_ranked(out)
-            self._stage("scan", time.perf_counter() - ts)
+            with self._stage("scan"):
+                out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
+                out = self._finish_ranked(out)
         dt = time.perf_counter() - t0
         with self._tel_lock:
             self.dispatch_log.append((B, nq, dt))
@@ -721,16 +730,15 @@ class _SegmentExec:
             if self.fused_safe:
                 return self._dispatch_safe_fused(qa, nq, B, prep_args, req,
                                                  ops)
-            ts = time.perf_counter()
-            hits_np = self.source().hit_counts(qa, B)[:nq]
-            self._stage("stage1", time.perf_counter() - ts)
+            with self._stage("stage1"):
+                hits_np = self.source().hit_counts(qa, B)[:nq]
             return self._prune_and_score(qa, B, prep_args, req, ops,
                                          hits_np=hits_np, tab_args=None)
-        ts = time.perf_counter()
-        out1 = self.probe_fn(B, emit_tables=True)(*qa, self.shard,
-                                                  *prep_args)
-        out1 = jax.block_until_ready(out1)
-        self._stage("stage1", time.perf_counter() - ts)
+        with self._stage("stage1"):
+            out1 = self.probe_fn(B, emit_tables=True)(*qa, self.shard,
+                                                      *prep_args)
+            with TR.span("serve.wait"):
+                out1 = jax.block_until_ready(out1)
         hits, tab_args = ((out1[0], tuple(out1[1:])) if self._use_prep
                           else (out1, ()))
         # selection sees only the real rows: bucket-padding copies must not
@@ -764,10 +772,9 @@ class _SegmentExec:
         if not rungs:
             # no rung beats the full scan — the host-selected path would
             # fall back for every survivor count; dispatch the scan direct
-            ts = time.perf_counter()
-            out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-            s, g, r, m = self._finish_ranked(out)
-            self._stage("scan", time.perf_counter() - ts)
+            with self._stage("scan"):
+                out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
+                s, g, r, m = self._finish_ranked(out)
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
             return s, g, r, m
         src = self.source()
@@ -775,12 +782,12 @@ class _SegmentExec:
             M = self._fused_rung if self._fused_rung in rungs else rungs[0]
         ndev = int(self.mesh.devices.size)
         for _ in range(2):
-            ts = time.perf_counter()
-            out = self.inverted_fused_fn(B, M, src.W)(
-                *qa, self.shard, src._keys_d, src._cols_d, ops)
-            s, g, r, m = self._finish_ranked(out[:4])
-            n = int(np.asarray(out[4]))     # replicated exact union size
-            self._stage("fused", time.perf_counter() - ts)
+            with self._stage("fused"):
+                out = self.inverted_fused_fn(B, M, src.W)(
+                    *qa, self.shard, src._keys_d, src._cols_d, ops)
+                s, g, r, m = self._finish_ranked(out[:4])
+                with TR.span("serve.wait"):
+                    n = int(np.asarray(out[4]))  # replicated union size
             need = PL.prune_rung(max(n, self.k_max), self.shape.prune_base,
                                  self.C, ndev)
             if n <= M:
@@ -792,10 +799,9 @@ class _SegmentExec:
                 break               # union outgrew the ladder → full scan
             with self._res_lock:
                 self._fused_rung = M = need
-        ts = time.perf_counter()
-        out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-        s, g, r, m = self._finish_ranked(out)
-        self._stage("scan", time.perf_counter() - ts)
+        with self._stage("scan"):
+            out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
+            s, g, r, m = self._finish_ranked(out)
         g = np.where(np.isfinite(s), g, -1).astype(np.int32)
         return s, g, r, m
 
@@ -804,35 +810,33 @@ class _SegmentExec:
         """Shared stage-2 tail of the safe dispatch: survivor selection,
         rung choice, pruned (or fallback full-scan) scoring.
         ``tab_args=None`` selects the table-free pruned plan."""
-        ts = time.perf_counter()
-        surv = PL.select_survivors(hits_np, prune="safe",
-                                   min_sample=req.min_sample)
-        ndev = int(self.mesh.devices.size)
-        rung = PL.prune_rung(max(len(surv), self.k_max),
-                             self.shape.prune_base, self.C, ndev)
-        self._stage("select", time.perf_counter() - ts)
+        with self._stage("select"):
+            surv = PL.select_survivors(hits_np, prune="safe",
+                                       min_sample=req.min_sample)
+            ndev = int(self.mesh.devices.size)
+            rung = PL.prune_rung(max(len(surv), self.k_max),
+                                 self.shape.prune_base, self.C, ndev)
         if rung is None:
-            ts = time.perf_counter()
-            out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-            s, g, r, m = self._finish_ranked(out)
-            self._stage("scan", time.perf_counter() - ts)
+            with self._stage("scan"):
+                out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
+                s, g, r, m = self._finish_ranked(out)
             # same id convention as the pruned dispatch below: −inf → −1
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
             return s, g, r, m
-        ts = time.perf_counter()
         idx = np.zeros((rung,), np.int32)
         idx[:len(surv)] = surv
         valid = np.arange(rung) < len(surv)
-        if tab_args is None:
-            out = self.prune_plain_fn(B, rung)(*qa, self.shard,
-                                               jnp.asarray(idx),
-                                               jnp.asarray(valid), ops)
-        else:
-            out = self.prune_fn(B, rung)(*qa, self.shard, jnp.asarray(idx),
-                                         jnp.asarray(valid), *tab_args,
-                                         *prep_args, ops)
-        s, g, r, m = self._finish_ranked(out)
-        self._stage("stage2", time.perf_counter() - ts)
+        with self._stage("stage2"):
+            if tab_args is None:
+                out = self.prune_plain_fn(B, rung)(*qa, self.shard,
+                                                   jnp.asarray(idx),
+                                                   jnp.asarray(valid), ops)
+            else:
+                out = self.prune_fn(B, rung)(*qa, self.shard,
+                                             jnp.asarray(idx),
+                                             jnp.asarray(valid), *tab_args,
+                                             *prep_args, ops)
+            s, g, r, m = self._finish_ranked(out)
         # stage-2 gids are already index-space; −inf rows (pruned / empty)
         # get id −1 so they can never alias a real column
         g = np.where(np.isfinite(s), g, -1).astype(np.int32)
@@ -848,33 +852,29 @@ class _SegmentExec:
         survivor union outgrows the rung ladder. (The fused device-resident
         select is safe-only: its union semantics cannot express per-row
         top-M truncation, so ``topm`` keeps the two-stage shape.)"""
-        ts = time.perf_counter()
-        hits_np = self.source().hit_counts(qa, B)[:nq]
-        self._stage("stage1", time.perf_counter() - ts)
-        ts = time.perf_counter()
-        surv = PL.select_survivors(hits_np, prune="topm",
-                                   min_sample=req.min_sample,
-                                   prune_m=self.shape.prune_m)
-        ndev = int(self.mesh.devices.size)
-        rung = PL.prune_rung(max(len(surv), self.k_max),
-                             self.shape.prune_base, self.C, ndev)
-        self._stage("select", time.perf_counter() - ts)
+        with self._stage("stage1"):
+            hits_np = self.source().hit_counts(qa, B)[:nq]
+        with self._stage("select"):
+            surv = PL.select_survivors(hits_np, prune="topm",
+                                       min_sample=req.min_sample,
+                                       prune_m=self.shape.prune_m)
+            ndev = int(self.mesh.devices.size)
+            rung = PL.prune_rung(max(len(surv), self.k_max),
+                                 self.shape.prune_base, self.C, ndev)
         if rung is None:
-            ts = time.perf_counter()
-            out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-            s, g, r, m = self._finish_ranked(out)
-            self._stage("scan", time.perf_counter() - ts)
+            with self._stage("scan"):
+                out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
+                s, g, r, m = self._finish_ranked(out)
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
             return s, g, r, m
-        ts = time.perf_counter()
         idx = np.zeros((rung,), np.int32)
         idx[:len(surv)] = surv
         valid = np.arange(rung) < len(surv)
-        out = self.prune_plain_fn(B, rung)(*qa, self.shard,
-                                           jnp.asarray(idx),
-                                           jnp.asarray(valid), ops)
-        s, g, r, m = self._finish_ranked(out)
-        self._stage("stage2", time.perf_counter() - ts)
+        with self._stage("stage2"):
+            out = self.prune_plain_fn(B, rung)(*qa, self.shard,
+                                               jnp.asarray(idx),
+                                               jnp.asarray(valid), ops)
+            s, g, r, m = self._finish_ranked(out)
         g = np.where(np.isfinite(s), g, -1).astype(np.int32)
         return s, g, r, m
 
@@ -939,10 +939,8 @@ class _SegmentExec:
                 part = tuple(jnp.concatenate(
                     [a, jnp.broadcast_to(a[-1:], (B - (e - s),) + a.shape[1:])])
                     for a in part)
-            with self._launch_lock():
-                ts = time.perf_counter()
+            with self._launch_lock(), self._stage("stage1"):
                 hc = self.source().hit_counts(part, B)
-                self._stage("stage1", time.perf_counter() - ts)
             rows.append(hc[:e - s])
             s = e
         return np.concatenate(rows, axis=0)
@@ -990,30 +988,26 @@ class _SegmentExec:
         return JoinabilityResult(**out)
 
     # -- telemetry -----------------------------------------------------------
-    def stage_stats(self) -> Tuple[Dict[str, float], Dict[str, int]]:
-        """Consistent copy of the per-stage telemetry accumulators
-        ``({stage: seconds}, {stage: count})``."""
+    def stage_stats(self) -> Dict[str, int]:
+        """Consistent copy of the per-stage counts ``{stage: count}``."""
         with self._tel_lock:
-            return dict(self._stage_s), dict(self._stage_n)
+            return dict(self._stage_n)
 
     def throughput(self) -> dict:
         """Latency/throughput numbers: lifetime totals for queries/qps,
         percentiles over the bounded recent-dispatch window, and the
-        per-stage breakdown (``stages[name] = {count, total_s}`` over
-        `_STAGE_NAMES`; ``device_dispatches`` sums the device-launch stages
-        — the counter the single-dispatch CI gate reads). The totals and
-        the log window are read under the telemetry lock, so concurrent
-        dispatches can't tear the percentiles."""
+        per-stage counts (``stages[name] = {count}`` over `_STAGE_NAMES`;
+        ``device_dispatches`` sums the device-launch stages — the counter
+        the single-dispatch CI gate reads). The totals and the log window
+        are read under the telemetry lock, so concurrent dispatches can't
+        tear the percentiles."""
         with self._tel_lock:
             queries = self._total_queries
             dispatches = self._total_dispatches
             total_s = self._total_s
             log = list(self.dispatch_log)
-            stage_s = dict(self._stage_s)
             stage_n = dict(self._stage_n)
-        stages = {name: dict(count=stage_n.get(name, 0),
-                             total_s=stage_s.get(name, 0.0))
-                  for name in sorted(set(stage_n) | set(stage_s))}
+        stages = {name: dict(count=n) for name, n in sorted(stage_n.items())}
         devd = sum(stage_n.get(name, 0) for name in _DEVICE_STAGES)
         if not queries:
             return dict(queries=0, dispatches=0, total_s=0.0, qps=0.0,
@@ -1127,9 +1121,8 @@ class Server:
         self._q_total = 0
         self._q_seconds = 0.0
         self._retired = dict(dispatches=0)
-        #: per-stage telemetry of retired segment executors (folded in by
+        #: per-stage counts of retired segment executors (folded in by
         #: `refresh()` so `throughput()` stays lifetime-accurate)
-        self._retired_stage_s: Dict[str, float] = {}
         self._retired_stage_n: Dict[str, int] = {}
 
         if _is_live(source):
@@ -1200,7 +1193,7 @@ class Server:
         one, complete with matching bases, and never a mixture."""
         if self._live is None or self._live.version == self._seen_version:
             return
-        with self._refresh_lock:
+        with TR.span("serve.refresh"), self._refresh_lock:
             if self._live.version == self._seen_version:
                 return  # another thread refreshed while we waited
             with self._live._lock:
@@ -1249,11 +1242,7 @@ class Server:
                 self._retired["dispatches"] += sum(
                     ex._total_dispatches for ex in gone)
                 for ex in gone:
-                    ss, sn = ex.stage_stats()
-                    for name, v in ss.items():
-                        self._retired_stage_s[name] = \
-                            self._retired_stage_s.get(name, 0.0) + v
-                    for name, v in sn.items():
+                    for name, v in ex.stage_stats().items():
                         self._retired_stage_n[name] = \
                             self._retired_stage_n.get(name, 0) + v
             self._entries = entries
@@ -1341,49 +1330,52 @@ class Server:
                 f"request k={req.k} exceeds ShapePolicy.k_max="
                 f"{self.shape.k_max}; raise k_max (a compile-time width) "
                 "or lower k")
-        if refresh:
-            self.refresh()
-        t_start = time.perf_counter()
-        # one atomic read of the published segment map: every per-segment
-        # dispatch below (and the global-id bases) comes from this single
-        # snapshot, however many refreshes land concurrently
+        with TR.span("serve.query"):
+            if refresh:
+                self.refresh()
+            t_start = time.perf_counter()
+            out = self._query_view(sketches, req)
+            with self._stats_lock:
+                self._q_total += len(out[0])
+                self._q_seconds += time.perf_counter() - t_start
+            return out
+
+    def _query_view(self, sketches: CorrelationSketch, req: PL.Request):
+        """`query_batch` on one atomic read of the published segment map:
+        every per-segment dispatch below (and the global-id bases) comes
+        from this single snapshot, however many refreshes land
+        concurrently."""
         view = self._view
         k = int(req.k)
         nq = int(jax.tree.leaves(sketches)[0].shape[0])
         empty = (np.full((nq, k), -np.inf, np.float32),
                  np.full((nq, k), -1, np.int32),
                  np.zeros((nq, k), np.float32), np.zeros((nq, k), np.float32))
-        if nq == 0:
-            return tuple(a[:0] for a in empty)
         parts = []
         for e in view:
-            if e.used == 0:
+            if e.used == 0 or nq == 0:
                 continue
             s, g, r, m = e.exec.query_batch(sketches, req)
-            parts.append((np.asarray(s), np.asarray(g) + e.base,
-                          np.asarray(r), np.asarray(m)))
+            with TR.span("serve.wait"):
+                parts.append((np.asarray(s), np.asarray(g) + e.base,
+                              np.asarray(r), np.asarray(m)))
         if not parts:
-            with self._stats_lock:
-                self._q_total += nq
-                self._q_seconds += time.perf_counter() - t_start
             return empty
-        s = np.concatenate([p[0] for p in parts], axis=1)
-        g = np.concatenate([p[1] for p in parts], axis=1)
-        r = np.concatenate([p[2] for p in parts], axis=1)
-        m = np.concatenate([p[3] for p in parts], axis=1)
         # deterministic combine: score desc, global id asc as tiebreak
-        out = empty
-        pick = np.lexsort((g, -s), axis=1)[:, :k]
-        take = lambda a: np.take_along_axis(a, pick, axis=1)
-        s, g, r, m = take(s), take(g), take(r), take(m)
-        kk = s.shape[1]
-        out[0][:, :kk] = s
-        out[1][:, :kk] = np.where(np.isfinite(s), g, -1)
-        out[2][:, :kk] = np.where(np.isfinite(s), r, 0.0)
-        out[3][:, :kk] = np.where(np.isfinite(s), m, 0.0)
-        with self._stats_lock:
-            self._q_total += nq
-            self._q_seconds += time.perf_counter() - t_start
+        with TR.span("serve.merge"):
+            s = np.concatenate([p[0] for p in parts], axis=1)
+            g = np.concatenate([p[1] for p in parts], axis=1)
+            r = np.concatenate([p[2] for p in parts], axis=1)
+            m = np.concatenate([p[3] for p in parts], axis=1)
+            out = empty
+            pick = np.lexsort((g, -s), axis=1)[:, :k]
+            take = lambda a: np.take_along_axis(a, pick, axis=1)
+            s, g, r, m = take(s), take(g), take(r), take(m)
+            kk = s.shape[1]
+            out[0][:, :kk] = s
+            out[1][:, :kk] = np.where(np.isfinite(s), g, -1)
+            out[2][:, :kk] = np.where(np.isfinite(s), r, 0.0)
+            out[3][:, :kk] = np.where(np.isfinite(s), m, 0.0)
         return out
 
     def query_columns(self, keys_list, values_list, *, chunk: int = 8192,
@@ -1491,20 +1483,15 @@ class Server:
                 q_total = self._q_total
                 q_seconds = self._q_seconds
                 retired = self._retired["dispatches"]
-                stage_s = dict(self._retired_stage_s)
                 stage_n = dict(self._retired_stage_n)
-            # per-stage breakdown across live + retired segment executors
+            # per-stage counts across live + retired segment executors
             # (DESIGN.md §11): every view entry owns a distinct exec, so
             # the sum is double-count-free
             for e in view:
-                ss, sn = e.exec.stage_stats()
-                for name, v in ss.items():
-                    stage_s[name] = stage_s.get(name, 0.0) + v
-                for name, v in sn.items():
+                for name, v in e.exec.stage_stats().items():
                     stage_n[name] = stage_n.get(name, 0) + v
-            stages = {name: dict(count=stage_n.get(name, 0),
-                                 total_s=stage_s.get(name, 0.0))
-                      for name in sorted(set(stage_n) | set(stage_s))}
+            stages = {name: dict(count=n)
+                      for name, n in sorted(stage_n.items())}
             out = dict(queries=q_total,
                        dispatches=retired
                        + sum(e.exec._total_dispatches for e in view),

@@ -224,9 +224,9 @@ def test_fused_rung_adaptation_single_steady_dispatch(rng):
     assert len(rungs) >= 2, rungs       # ladder tall enough to adapt within
     req = PL.Request(k=5, scorer="s2", prune="safe")
     srv.query_batch(sks, request=req)   # adaptation call (may retry once)
-    _, n0 = ex.stage_stats()
+    n0 = ex.stage_stats()
     srv.query_batch(sks, request=req)
-    _, n1 = ex.stage_stats()
+    n1 = ex.stage_stats()
     assert n1.get("fused", 0) - n0.get("fused", 0) == 1, (n0, n1)
     assert n1.get("stage1", 0) == n0.get("stage1", 0)    # no dense probe
     assert n1.get("stage2", 0) == n0.get("stage2", 0)    # no second launch
@@ -245,7 +245,7 @@ def test_fused_ladder_overflow_falls_back_to_scan(rng):
     req = PL.Request(k=5, scorer="s2", prune="safe")
     ex = _exec(srv)
     fused = srv.query_batch(sks, request=req)
-    _, n = ex.stage_stats()
+    n = ex.stage_stats()
     assert n.get("scan", 0) >= 1, n
     ex.fused_safe = False
     try:
@@ -302,7 +302,7 @@ def test_stage_telemetry_shape_and_aggregation(rng):
     tp = srv.throughput()
     assert set(tp["stages"]) <= set(SV._STAGE_NAMES)
     for rec in tp["stages"].values():
-        assert rec["count"] >= 1 and rec["total_s"] >= 0.0
+        assert set(rec) == {"count"} and rec["count"] >= 1
     assert tp["stages"]["fused"]["count"] >= 1
     assert tp["stages"]["scan"]["count"] >= 1          # the prune='off' call
     # device_dispatches counts device launches only — host-side select and
@@ -311,8 +311,8 @@ def test_stage_telemetry_shape_and_aggregation(rng):
                for s in SV._DEVICE_STAGES)
     assert tp["device_dispatches"] == want
     ex = _exec(srv)
-    s_map, n_map = ex.stage_stats()
-    assert set(s_map) == set(n_map)
+    assert ex.stage_stats() == {name: rec["count"]
+                                for name, rec in tp["stages"].items()}
 
 
 def test_stage_telemetry_survives_segment_retirement(rng):
@@ -369,5 +369,5 @@ def test_auto_resolves_per_segment(rng, monkeypatch):
     sks = _sketches(rng, nq=2)
     out = srv2.query_batch(sks, request=PL.Request(k=5, prune="safe"))
     assert np.asarray(out[0]).shape == (2, 5)
-    _, n = _exec(srv2).stage_stats()
+    n = _exec(srv2).stage_stats()
     assert n.get("fused", 0) + n.get("scan", 0) >= 1

@@ -107,7 +107,7 @@ _BODY = """
     assert not bad, f"{len(bad)} inverted-source mismatches"
     assert cache_i.misses == misses_i, "fused sweep compiled post-warmup"
     for srv in (srv1i, srv8i):
-        stats = [e.exec.stage_stats()[1]
+        stats = [e.exec.stage_stats()
                  for e in srv._entries.values()]
         assert sum(n.get("fused", 0) for n in stats) > 0, \\
             f"fused plan never dispatched (D={srv.shape.mesh_shards})"
