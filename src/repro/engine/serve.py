@@ -18,8 +18,8 @@ the compiled plans of `repro.engine.plans`:
     estimator × k ≤ k_max × prune mode triggers **zero compiles**
     (`CompileCache.misses` is the counter the tests pin);
   * **batched sketch construction** — incoming query columns are cut into
-    fixed-length row chunks, sketched with one vmapped `build_sketch` call,
-    and folded with the (exact) KMV merge;
+    fixed-length row chunks, sketched with a vmapped `build_sketch` and
+    folded with the (exact) KMV merge, in one compiled program;
   * **pad-to-bucket batching** + **measured-cost planning** — request
     batches are covered by the cheapest mix of warmed bucket dispatches
     (exact DP over `warmup()` timings);
@@ -69,29 +69,32 @@ def build_query_sketches(keys_list: Sequence[np.ndarray],
                          values_list: Sequence[np.ndarray], *,
                          n: int, agg: Agg = Agg.MEAN,
                          chunk: int = 8192) -> CorrelationSketch:
-    """Sketch a batch of query columns in one vmapped pass.
+    """Sketch a batch of query columns in one compiled program.
 
-    Every column is padded to a common number of fixed-length ``chunk`` row
-    blocks (validity-masked), all blocks are sketched with a single vmapped
-    `build_sketch`, and each query's block sketches are folded with the KMV
-    merge — exact by the closure property, identical to sketching each
-    column alone. Returns a `CorrelationSketch` whose leaves carry a leading
-    ``[NQ]`` axis, ready for `repro.engine.index.query_arrays`.
+    Every column is cut into fixed-length ``chunk`` row blocks
+    (validity-masked; only real blocks are laid out), and one jitted
+    program sketches all blocks with a vmapped `build_sketch` and folds each
+    query's block sketches with the KMV merge — exact by the closure
+    property, identical to sketching each column alone. The program
+    compiles once per input shape (chunk length, ``NQ``, total blocks, fold
+    rounds); `front_stats` counts calls and compiles. Returns a
+    `CorrelationSketch` whose leaves carry a leading ``[NQ]`` axis, ready
+    for `repro.engine.index.query_arrays`.
     """
     assert len(keys_list) == len(values_list) and keys_list, "empty query batch"
+    _count("calls")
     with TR.span("front.sketch"):
         return _build_query_sketches(keys_list, values_list, n, agg, chunk)
 
 
 def _build_query_sketches(keys_list, values_list, n, agg, chunk):
-    nq = len(keys_list)
     # ragged layout: only real chunks are materialised and sketched, so one
     # long query costs its own chunks, not nq × its chunk count. (The fold
-    # below still runs max-chunk-count rounds over all nq rows, but each
-    # round is an n-sized merge — noise next to the chunk-sized builds.)
+    # still runs max-chunk-count rounds over all nq rows, but each round is
+    # an n-sized merge — noise next to the chunk-sized builds.)
     with TR.span("front.layout"):
-        counts = [max(1, -(-len(k) // chunk)) for k in keys_list]
-        starts = np.cumsum([0] + counts)
+        counts = np.array([max(1, -(-len(k) // chunk)) for k in keys_list])
+        starts = np.cumsum(np.concatenate([[0], counts]))
         total = int(starts[-1])
         keys = np.zeros((total, chunk), np.uint32)
         vals = np.zeros((total, chunk), np.float32)
@@ -110,28 +113,56 @@ def _build_query_sketches(keys_list, values_list, n, agg, chunk):
                                       ).reshape(counts[i], chunk)
             offs[s:s + counts[i]] = np.arange(counts[i],
                                               dtype=np.float32) * chunk
+        # fold round j (row j-1) merges chunk j into every query that still
+        # has one; exhausted queries keep their fold result
+        j = np.arange(1, counts.max())[:, None]
+        has = counts[None, :] > j
+        sel = np.where(has, starts[None, :-1] + j, 0).astype(np.int32)
 
-    with TR.span("front.chunks"):
-        build = jax.vmap(lambda k, v, ok, off: build_sketch(
-            k, v, n=n, agg=agg, valid=ok, order_offset=off))
-        parts = build(jnp.asarray(keys), jnp.asarray(vals),
-                      jnp.asarray(valid), jnp.asarray(offs))
+    with TR.span("front.build"):
+        return _sketch_and_fold(keys, vals, valid, offs,
+                                starts[:-1].astype(np.int32), sel, has,
+                                n=n, agg=agg)
 
-    # fold round j merges chunk j into every query that still has one;
-    # exhausted queries keep their fold result via the per-row select
-    with TR.span("front.fold"):
-        out = jax.tree.map(lambda a: a[jnp.asarray(starts[:-1])], parts)
-        for j in range(1, max(counts)):
-            sel = np.array([starts[i] + j if counts[i] > j else 0
-                            for i in range(nq)])
-            has = jnp.asarray(np.array([counts[i] > j for i in range(nq)]))
-            nxt = jax.tree.map(lambda a: a[jnp.asarray(sel)], parts)
-            merged = jax.vmap(merge)(out, nxt)
-            out = jax.tree.map(
-                lambda m_, o: jnp.where(
-                    has.reshape((nq,) + (1,) * (o.ndim - 1)), m_, o),
-                merged, out)
+
+@functools.partial(jax.jit, static_argnames=("n", "agg"))
+def _sketch_and_fold(keys, vals, valid, offs, first, sel, has, *, n, agg):
+    """All ``[T, chunk]`` row blocks sketched, then one fold round per row
+    of ``sel``/``has`` (``[R, NQ]``: each query's next block, and whether it
+    has one) over the ``[NQ]`` sketches that start at blocks ``first``."""
+    _count("programs")                    # runs once per trace
+    parts = jax.vmap(lambda k, v, ok, off: build_sketch(
+        k, v, n=n, agg=agg, valid=ok, order_offset=off))(
+            keys, vals, valid, offs)
+
+    def fold(out, rnd):
+        sel_j, has_j = rnd
+        merged = jax.vmap(merge)(out, jax.tree.map(lambda a: a[sel_j], parts))
+        return jax.tree.map(
+            lambda m_, o: jnp.where(
+                has_j.reshape(has_j.shape + (1,) * (o.ndim - 1)), m_, o),
+            merged, out), None
+
+    out, _ = jax.lax.scan(fold, jax.tree.map(lambda a: a[first], parts),
+                          (sel, has))
     return out
+
+
+_front_lock = threading.Lock()
+_front = {"calls": 0, "programs": 0}
+
+
+def _count(name: str) -> None:
+    with _front_lock:
+        _front[name] += 1
+
+
+def front_stats() -> Dict[str, int]:
+    """Front-end counters since the process started: ``calls`` of
+    `build_query_sketches` and ``programs``, the traces (compiles) of its
+    program. A warmed server keeps ``programs`` flat while ``calls`` grows."""
+    with _front_lock:
+        return dict(_front)
 
 
 class CompileCache:

@@ -29,7 +29,7 @@ import jax
 #: (`serve.build_query_sketches`), scheduler (`AsyncScheduler`), server
 #: (`Server`, `_SegmentExec`; ``serve.wait`` is a worker blocked on the
 #: device)
-SPANS = ("front.sketch", "front.layout", "front.chunks", "front.fold",
+SPANS = ("front.sketch", "front.layout", "front.build",
          "sched.admit", "sched.batch", "sched.merge",
          "serve.query", "serve.refresh", "serve.merge",
          "serve.stage1", "serve.stage2", "serve.scan", "serve.topm",

@@ -6,6 +6,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import build_sketch
+from repro.core.sketch import Agg, build_sketch_streaming
 from repro.data.pipeline import Table, sbn_pair
 from repro.engine import index as IX
 from repro.engine import plans as PL
@@ -155,3 +156,72 @@ def test_score_chunk_padding_bounds_memory(corpus):
     s2, r2, m2, _ = Q.score_shard(*qa, shard, eq)
     np.testing.assert_allclose(np.asarray(s0), np.asarray(s2), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(m0), np.asarray(m2))
+
+
+def _lengths(c, nq, chunk):
+    """Row counts whose longest column spans ``c`` chunks: one ragged
+    column alone, or three of different chunk counts."""
+    if nq == 1:
+        return [c * chunk - 17]
+    return [c * chunk - 5, chunk // 3, (c + 1) // 2 * chunk]
+
+
+@pytest.mark.parametrize("nq", [1, 3])
+@pytest.mark.parametrize("c", [1, 2, 3, 8])
+@pytest.mark.parametrize("agg", list(Agg))
+def test_compiled_front_end_equals_streaming_build(agg, c, nq):
+    """The one-program build (vmapped chunk sketches + in-program KMV fold)
+    equals, leaf for leaf, the per-column streaming build at the same chunk:
+    every aggregation (FIRST/LAST read the row order), 1–8 chunks, lone and
+    ragged batches, repeated keys across chunks and NaN rows."""
+    chunk, n = 256, 32
+    rng = np.random.default_rng([c, nq, list(Agg).index(agg)])
+    cols = []
+    for ln in _lengths(c, nq, chunk):
+        k = rng.integers(0, 3 * chunk, size=ln).astype(np.uint32)
+        v = rng.normal(size=ln).astype(np.float32)
+        v[rng.random(ln) < 0.02] = np.nan
+        cols.append((k, v))
+    sks = SV.build_query_sketches([k for k, _ in cols], [v for _, v in cols],
+                                  n=n, agg=agg, chunk=chunk)
+    assert sks.agg == agg and sks.key_hash.shape == (nq, n)
+    for i, (k, v) in enumerate(cols):
+        ref = build_sketch_streaming(k, v, n=n, agg=agg, chunk=chunk)
+        got = jax.tree.map(lambda a, i=i: a[i], sks)
+        for name, g, r in zip(ref.__dataclass_fields__, jax.tree.leaves(got),
+                              jax.tree.leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r),
+                                          err_msg=name)
+
+
+def test_front_end_warm_shape_compiles_nothing():
+    """A second call at a warmed shape lowers no JAX program: the front
+    end's `programs` count stays flat while `calls` grows, and a new chunk
+    count costs exactly one more program."""
+    rng = np.random.default_rng(5)
+
+    def call(rows):
+        k = rng.integers(0, 5000, size=rows).astype(np.uint32)
+        v = rng.normal(size=rows).astype(np.float32)
+        return jax.block_until_ready(
+            SV.build_query_sketches([k], [v], n=32, chunk=512))
+
+    call(1500)                                       # warm: 3 chunks
+    lowerings = []
+    hear = lambda event, *a, **kw: lowerings.append(event) \
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration" else None
+    jax.monitoring.register_event_duration_secs_listener(hear)
+    try:
+        before = SV.front_stats()
+        call(1400)                                   # same shape, new data
+        mid = SV.front_stats()
+        assert lowerings == []
+        assert mid["calls"] == before["calls"] + 1
+        assert mid["programs"] == before["programs"]
+        call(2000)                                   # 4 chunks: new shape
+        after = SV.front_stats()
+        assert after["programs"] == mid["programs"] + 1
+        assert after["calls"] == mid["calls"] + 1
+        assert len(lowerings) == 1
+    finally:
+        jax.monitoring.unregister_event_duration_listener(hear)

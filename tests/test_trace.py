@@ -82,8 +82,8 @@ def test_on_records_every_layer_of_a_served_query(fresh, server):
         TR.enable(False)
     got = TR.totals()
     assert set(got) <= set(TR.SPANS)
-    for name in ("front.sketch", "front.layout", "front.chunks",
-                 "front.fold", "sched.admit", "sched.batch", "serve.query",
+    for name in ("front.sketch", "front.layout", "front.build",
+                 "sched.admit", "sched.batch", "serve.query",
                  "serve.fused", "serve.wait"):
         assert got[name]["count"] >= 1, name
         assert got[name]["wall_ns"] > 0 and got[name]["cpu_ns"] >= 0, name
