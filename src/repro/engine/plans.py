@@ -1228,6 +1228,12 @@ def make_pruned_fn(mesh, C_total: int, n: int, shape: ShapePolicy, M: int,
     return jax.jit(fn)
 
 
+def topm_width(shape: ShapePolicy, C_local: int) -> int:
+    """Candidates per query row and device shard that the fused ``topm``
+    plan scores: ``prune_m``, never under ``k_max``, never over the shard."""
+    return max(min(int(shape.prune_m), C_local), min(shape.k_max, C_local))
+
+
 def make_topm_fn(mesh, C_total: int, n: int, shape: ShapePolicy, batch: int,
                  with_prep: bool = False):
     """Build the fused ``prune='topm'`` plan: probe, per-row top-M filter,
@@ -1252,7 +1258,7 @@ def make_topm_fn(mesh, C_total: int, n: int, shape: ShapePolicy, batch: int,
     C_local = C_total // ndev
     k = shape.k_max
     host_combine = _plan_combine(shape, ndev)
-    M = max(min(int(shape.prune_m), C_local), min(k, C_local))
+    M = topm_width(shape, C_local)
     chunk, _, nb = _chunk_layout(C_local, shape.score_chunk)
     T = chunk * n + 1
     B = int(batch)

@@ -273,6 +273,12 @@ _STAGE_NAMES = _DEVICE_STAGES + ("select", "combine")
 _STAGE_SPANS = {name: f"serve.{name}" for name in _STAGE_NAMES}
 
 
+def _add_counts(into: Dict[str, int], counts: Dict[str, int]) -> None:
+    """Add ``counts`` into ``into`` key by key."""
+    for name, v in counts.items():
+        into[name] = into.get(name, 0) + v
+
+
 class _SegmentExec:
     """Plan executor for one resident (shard, `ShapePolicy`) pair — the
     engine room behind `Server` (one per segment on a live index, exactly
@@ -355,6 +361,12 @@ class _SegmentExec:
         #: §11), under `_tel_lock` like the dispatch log. Their times are
         #: the spans of the same names (`repro.engine.trace`)
         self._stage_n: Dict[str, int] = {}
+        #: candidate rows handed to stage-2 scoring, by estimator, under
+        #: `_tel_lock`: bucket width × the rung M (pruned and fused plans),
+        #: × ``prune_m`` per device (fused topm) or × the padded C (scan).
+        #: The rank estimators' work scales with it; their time is the
+        #: device trace's, since they run inside the fused programs
+        self._scored_n: Dict[str, int] = {}
         #: fused inverted-safe dispatch (DESIGN.md §11): last sufficient
         #: survivor rung (adapted per dispatch, guarded by ``_res_lock``)
         #: and the toggle back to the legacy two-dispatch host-selected
@@ -673,12 +685,18 @@ class _SegmentExec:
         return list(_plan_cover(nq, self.buckets, costs))
 
     # -- dispatch ------------------------------------------------------------
-    def _stage(self, name: str):
-        """Count one invocation of stage ``name`` under ``_tel_lock`` (a
-        racy ``+=`` under concurrent dispatches silently loses updates) and
-        return its span, ``with self._stage("fused"): ...``."""
+    def _stage(self, name: str, req: Optional[PL.Request] = None,
+               rows: int = 0):
+        """Count one invocation of stage ``name`` — and the ``rows``
+        candidate rows it hands to ``req``'s estimator — under
+        ``_tel_lock`` (a racy ``+=`` under concurrent dispatches silently
+        loses updates) and return its span, ``with self._stage("fused"):
+        ...``."""
         with self._tel_lock:
             self._stage_n[name] = self._stage_n.get(name, 0) + 1
+            if rows:
+                est = req.estimator
+                self._scored_n[est] = self._scored_n.get(est, 0) + rows
         return TR.span(_STAGE_SPANS[name])
 
     def _finish_ranked(self, out):
@@ -722,7 +740,9 @@ class _SegmentExec:
                 out = self._dispatch_topm_sourced(qa, nq, B, prep_args, req,
                                                   ops)
             else:
-                with self._stage("topm"):
+                ndev = int(self.mesh.devices.size)
+                rows = B * ndev * PL.topm_width(self.shape, self.C // ndev)
+                with self._stage("topm", req, rows):
                     out = self.topm_fn(B)(*qa, self.shard, *prep_args, ops)
                     s, g, r, m = self._finish_ranked(out)
                 g = np.where(np.isfinite(s), g, -1).astype(np.int32)
@@ -730,7 +750,7 @@ class _SegmentExec:
         elif req.prune == "safe":
             out = self._dispatch_safe(qa, nq, B, prep_args, req, ops)
         else:
-            with self._stage("scan"):
+            with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
                 out = self._finish_ranked(out)
         dt = time.perf_counter() - t0
@@ -803,7 +823,7 @@ class _SegmentExec:
         if not rungs:
             # no rung beats the full scan — the host-selected path would
             # fall back for every survivor count; dispatch the scan direct
-            with self._stage("scan"):
+            with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
                 s, g, r, m = self._finish_ranked(out)
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
@@ -813,7 +833,7 @@ class _SegmentExec:
             M = self._fused_rung if self._fused_rung in rungs else rungs[0]
         ndev = int(self.mesh.devices.size)
         for _ in range(2):
-            with self._stage("fused"):
+            with self._stage("fused", req, B * M):
                 out = self.inverted_fused_fn(B, M, src.W)(
                     *qa, self.shard, src._keys_d, src._cols_d, ops)
                 s, g, r, m = self._finish_ranked(out[:4])
@@ -830,7 +850,7 @@ class _SegmentExec:
                 break               # union outgrew the ladder → full scan
             with self._res_lock:
                 self._fused_rung = M = need
-        with self._stage("scan"):
+        with self._stage("scan", req, B * self.C):
             out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
             s, g, r, m = self._finish_ranked(out)
         g = np.where(np.isfinite(s), g, -1).astype(np.int32)
@@ -848,7 +868,7 @@ class _SegmentExec:
             rung = PL.prune_rung(max(len(surv), self.k_max),
                                  self.shape.prune_base, self.C, ndev)
         if rung is None:
-            with self._stage("scan"):
+            with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
                 s, g, r, m = self._finish_ranked(out)
             # same id convention as the pruned dispatch below: −inf → −1
@@ -857,7 +877,7 @@ class _SegmentExec:
         idx = np.zeros((rung,), np.int32)
         idx[:len(surv)] = surv
         valid = np.arange(rung) < len(surv)
-        with self._stage("stage2"):
+        with self._stage("stage2", req, B * rung):
             if tab_args is None:
                 out = self.prune_plain_fn(B, rung)(*qa, self.shard,
                                                    jnp.asarray(idx),
@@ -893,7 +913,7 @@ class _SegmentExec:
             rung = PL.prune_rung(max(len(surv), self.k_max),
                                  self.shape.prune_base, self.C, ndev)
         if rung is None:
-            with self._stage("scan"):
+            with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
                 s, g, r, m = self._finish_ranked(out)
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
@@ -901,7 +921,7 @@ class _SegmentExec:
         idx = np.zeros((rung,), np.int32)
         idx[:len(surv)] = surv
         valid = np.arange(rung) < len(surv)
-        with self._stage("stage2"):
+        with self._stage("stage2", req, B * rung):
             out = self.prune_plain_fn(B, rung)(*qa, self.shard,
                                                jnp.asarray(idx),
                                                jnp.asarray(valid), ops)
@@ -1024,27 +1044,36 @@ class _SegmentExec:
         with self._tel_lock:
             return dict(self._stage_n)
 
+    def scored_rows(self) -> Dict[str, int]:
+        """Consistent copy of the scored candidate rows ``{estimator:
+        rows}``."""
+        with self._tel_lock:
+            return dict(self._scored_n)
+
     def throughput(self) -> dict:
         """Latency/throughput numbers: lifetime totals for queries/qps,
         percentiles over the bounded recent-dispatch window, and the
         per-stage counts (``stages[name] = {count}`` over `_STAGE_NAMES`;
         ``device_dispatches`` sums the device-launch stages — the counter
-        the single-dispatch CI gate reads). The totals and the log window
-        are read under the telemetry lock, so concurrent dispatches can't
-        tear the percentiles."""
+        the single-dispatch CI gate reads; ``scored_rows`` the candidate
+        rows handed to stage-2 scoring, by estimator). The totals and the
+        log window are read under the telemetry lock, so concurrent
+        dispatches can't tear the percentiles."""
         with self._tel_lock:
             queries = self._total_queries
             dispatches = self._total_dispatches
             total_s = self._total_s
             log = list(self.dispatch_log)
             stage_n = dict(self._stage_n)
+            scored = dict(self._scored_n)
         stages = {name: dict(count=n) for name, n in sorted(stage_n.items())}
         devd = sum(stage_n.get(name, 0) for name in _DEVICE_STAGES)
         if not queries:
             return dict(queries=0, dispatches=0, total_s=0.0, qps=0.0,
                         dispatch_p50_ms=0.0, dispatch_p90_ms=0.0,
                         dispatch_p99_ms=0.0, per_query_ms=0.0,
-                        stages=stages, device_dispatches=devd)
+                        stages=stages, device_dispatches=devd,
+                        scored_rows=scored)
         lat_ms = np.array([t * 1e3 for _, _, t in log])
         return dict(
             queries=queries, dispatches=dispatches,
@@ -1054,7 +1083,7 @@ class _SegmentExec:
             dispatch_p90_ms=float(np.percentile(lat_ms, 90)),
             dispatch_p99_ms=float(np.percentile(lat_ms, 99)),
             per_query_ms=1e3 * total_s / max(queries, 1),
-            stages=stages, device_dispatches=devd)
+            stages=stages, device_dispatches=devd, scored_rows=scored)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1152,9 +1181,11 @@ class Server:
         self._q_total = 0
         self._q_seconds = 0.0
         self._retired = dict(dispatches=0)
-        #: per-stage counts of retired segment executors (folded in by
-        #: `refresh()` so `throughput()` stays lifetime-accurate)
+        #: per-stage counts and scored rows of retired segment executors
+        #: (folded in by `refresh()` so `throughput()` stays
+        #: lifetime-accurate)
         self._retired_stage_n: Dict[str, int] = {}
+        self._retired_scored: Dict[str, int] = {}
 
         if _is_live(source):
             self._live = source
@@ -1273,9 +1304,8 @@ class Server:
                 self._retired["dispatches"] += sum(
                     ex._total_dispatches for ex in gone)
                 for ex in gone:
-                    for name, v in ex.stage_stats().items():
-                        self._retired_stage_n[name] = \
-                            self._retired_stage_n.get(name, 0) + v
+                    _add_counts(self._retired_stage_n, ex.stage_stats())
+                    _add_counts(self._retired_scored, ex.scored_rows())
             self._entries = entries
             self._order = order
             self.names = names
@@ -1515,12 +1545,13 @@ class Server:
                 q_seconds = self._q_seconds
                 retired = self._retired["dispatches"]
                 stage_n = dict(self._retired_stage_n)
-            # per-stage counts across live + retired segment executors
-            # (DESIGN.md §11): every view entry owns a distinct exec, so
-            # the sum is double-count-free
+                scored = dict(self._retired_scored)
+            # per-stage counts and scored rows across live + retired
+            # segment executors (DESIGN.md §11): every view entry owns a
+            # distinct exec, so the sum is double-count-free
             for e in view:
-                for name, v in e.exec.stage_stats().items():
-                    stage_n[name] = stage_n.get(name, 0) + v
+                _add_counts(stage_n, e.exec.stage_stats())
+                _add_counts(scored, e.exec.scored_rows())
             stages = {name: dict(count=n)
                       for name, n in sorted(stage_n.items())}
             out = dict(queries=q_total,
@@ -1532,7 +1563,8 @@ class Server:
                        segments=len(view),
                        stages=stages,
                        device_dispatches=sum(stage_n.get(name, 0)
-                                             for name in _DEVICE_STAGES))
+                                             for name in _DEVICE_STAGES),
+                       scored_rows=scored)
         sched = self._scheduler
         if sched is not None:
             out.update(sched.queue_stats())

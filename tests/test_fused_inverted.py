@@ -315,6 +315,33 @@ def test_stage_telemetry_shape_and_aggregation(rng):
                                 for name, rec in tp["stages"].items()}
 
 
+def test_scored_rows_count_rung_and_scan_width(rng, monkeypatch):
+    """``throughput()["scored_rows"]`` counts, by estimator, the candidate
+    rows each dispatch hands to scoring: bucket width × the survivor rung
+    of every fused dispatch, bucket width × the padded column count of
+    every scan."""
+    idx, srv = _server(rng, pad_to=32)
+    ex = _exec(srv)
+    rungs = []
+    fused_fn = ex.inverted_fused_fn
+    monkeypatch.setattr(ex, "inverted_fused_fn", lambda B, M, W: (
+        rungs.append((B, M)) or fused_fn(B, M, W)))
+    sks = _sketches(rng, nq=3)
+    assert srv.throughput()["scored_rows"] == {}
+    srv.query_batch(sks, request=PL.Request(k=5, prune="safe",
+                                            estimator="spearman"))
+    st = srv.throughput()["stages"]
+    scans = st.get("scan", {}).get("count", 0)
+    assert rungs and st["fused"]["count"] == len(rungs)
+    want = sum(B * M for B, M in rungs) + scans * 4 * ex.C
+    assert srv.throughput()["scored_rows"] == {"spearman": want}
+    srv.query_batch(sks, request=PL.Request(k=5, prune="off"))
+    assert ex.C == 32
+    assert srv.throughput()["scored_rows"] == {"spearman": want,
+                                               "pearson": 4 * 32}
+    assert ex.scored_rows() == srv.throughput()["scored_rows"]
+
+
 def test_stage_telemetry_survives_segment_retirement(rng):
     """Stage totals from retired segment executors fold into the server
     aggregate (same discipline as the retired dispatch counters)."""
@@ -329,11 +356,14 @@ def test_stage_telemetry_survives_segment_retirement(rng):
     srv.query_batch(sks, request=PL.Request(k=4, prune="safe"))
     before = srv.throughput()["stages"]
     n_before = sum(rec["count"] for rec in before.values())
+    rows_before = srv.throughput()["scored_rows"]
     live.compact()                      # retires every live executor
     srv.refresh()
     after = srv.throughput()["stages"]
     n_after = sum(rec["count"] for rec in after.values())
     assert n_after >= n_before > 0, (before, after)
+    assert rows_before["pearson"] > 0
+    assert srv.throughput()["scored_rows"] == rows_before
 
 
 # ---------------------------------------------------------------------------

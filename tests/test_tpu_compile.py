@@ -2,8 +2,8 @@
 
 Interpret mode accepts block shapes and VMEM footprints the TPU compiler
 refuses, so every main-path kernel is compiled here with the chip's own
-compiler, at the sketch sizes the system serves (n = 256) and the largest
-one `rank_transform` documents (n = 1024), for a chip that is described,
+compiler, at the sketch sizes the benchmark serves (n = 256 and n = 1024,
+the largest one `rank_transform` documents), for a chip that is described,
 not attached. Nothing runs: a compile that passes says the kernel tiles
 and fits, not that it is fast or right (the interpret-mode tests pin the
 results). Each assertion requires ``tpu_custom_call`` in the compiled
