@@ -471,13 +471,27 @@ def _est_select(est, pearson_fn, spearman_fn, rin_fn, qn_fn):
     return jax.lax.switch(idx, fns)
 
 
+def _no_tiles():
+    return jnp.zeros((2,), jnp.int32)
+
+
+def _ranked(a, b, w, kind: str, shape: ShapePolicy):
+    """A rank-family branch: (r from the fused rank moments, the kernel's
+    [live, total] pair tiles for the same mask)."""
+    return (K.pearson_from_moments(K.rank_moments(a, b, w, kind,
+                                                  shape.kernels)),
+            K.rank_tiles(w, shape.kernels))
+
+
 def _score_block(q_kh, q_val, q_mask, kh, vals, mask, shape: ShapePolicy,
                  est, prep=None):
     """probe stage for one candidate block: moments → (r, m) under the
     requested estimator.
 
     Query arrays are ``[n_q]`` (single) or ``[B, n_q]`` (batched); candidate
-    arrays are always ``[C, n]``. Returns moments ``[..., C, 6]``, r ``[..., C]``.
+    arrays are always ``[C, n]``. Returns moments ``[..., C, 6]``, r
+    ``[..., C]`` and the rank kernel's pair tiles ``i32[2]`` (`_no_tiles`
+    unless a rank estimator ran the Pallas kernel).
     """
     batched = q_kh.ndim == 2
     if uses_sortmerge(shape):
@@ -505,17 +519,17 @@ def _score_block(q_kh, q_val, q_mask, kh, vals, mask, shape: ShapePolicy,
         def _ranked_r(kind):
             def _r():
                 _, a, b, w = intersect()
-                return K.pearson_from_moments(
-                    K.rank_moments(a, b, w, kind, shape.kernels))
+                return _ranked(a, b, w, kind, shape)
             return _r
 
         def _qn_r():
             _, a, b, w = intersect()
-            return K.qn_correlation(a, b, w, shape.kernels)
+            return K.qn_correlation(a, b, w, shape.kernels), _no_tiles()
 
-        r = _est_select(est, lambda: K.pearson_from_moments(mom),
-                        _ranked_r("spearman"), _ranked_r("rin"), _qn_r)
-        return mom, r
+        r, tiles = _est_select(
+            est, lambda: (K.pearson_from_moments(mom), _no_tiles()),
+            _ranked_r("spearman"), _ranked_r("rin"), _qn_r)
+        return mom, r, tiles
     join = (K.sketch_join_moments_batched if batched else K.sketch_join_moments)
     mom, aligned, hit = join(q_kh, q_val, q_mask, kh, vals, mask,
                              shape.kernels)
@@ -523,18 +537,17 @@ def _score_block(q_kh, q_val, q_mask, kh, vals, mask, shape: ShapePolicy,
     def _ranked_kernel(kind):
         def _r():
             qv = jnp.broadcast_to(q_val[..., None, :] * hit, aligned.shape)
-            return K.pearson_from_moments(
-                K.rank_moments(qv, aligned, hit, kind, shape.kernels))
+            return _ranked(qv, aligned, hit, kind, shape)
         return _r
 
     def _qn_kernel():
         qv = jnp.broadcast_to(q_val[..., None, :] * hit, aligned.shape)
-        return K.qn_correlation(qv, aligned, hit, shape.kernels)
+        return K.qn_correlation(qv, aligned, hit, shape.kernels), _no_tiles()
 
-    r = _est_select(est, lambda: K.pearson_from_moments(mom),
-                    _ranked_kernel("spearman"), _ranked_kernel("rin"),
-                    _qn_kernel)
-    return mom, r
+    r, tiles = _est_select(
+        est, lambda: (K.pearson_from_moments(mom), _no_tiles()),
+        _ranked_kernel("spearman"), _ranked_kernel("rin"), _qn_kernel)
+    return mom, r, tiles
 
 
 def _chunk_layout(C: int, score_chunk: int):
@@ -547,7 +560,9 @@ def _chunk_layout(C: int, score_chunk: int):
 def _shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax, shard: IndexShard,
                  shape: ShapePolicy, est, alpha,
                  prep: Optional[PreppedShard] = None):
-    """Chunked scan over a shard's candidates → (r, m, ci_len), each [..., C].
+    """Chunked scan over a shard's candidates → (r, m, ci_len), each
+    [..., C], and the rank kernel's pair tiles ``i32[2]`` summed over the
+    chunks.
 
     Candidates stream through in ``score_chunk`` blocks under ``lax.map`` so
     the (chunk, n_q, n) match tensor stays O(chunk·n²) regardless of shard
@@ -580,18 +595,19 @@ def _shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax, shard: IndexShard,
                                 shape, est,
                                 prep=(cdk, csid) if have_prep else None)
 
-        mom, r = jax.lax.map(one, (resh(kh), resh(vals), resh(mask),
-                                   *blocks_prep))
+        mom, r, tiles = jax.lax.map(one, (resh(kh), resh(vals), resh(mask),
+                                          *blocks_prep))
+        tiles = tiles.sum(0)
         # lax.map stacks the chunk axis in front: [nb, ..., chunk, ·] → [..., Cp, ·]
         mom = jnp.moveaxis(mom, 0, -3).reshape(q_kh.shape[:-1] + (Cp, mom.shape[-1]))
         r = jnp.moveaxis(r, 0, -2).reshape(q_kh.shape[:-1] + (Cp,))
         mom = mom[..., :C, :]
         r = r[..., :C]
     else:
-        mom, r = _score_block(q_kh, q_val, q_mask, kh, vals, mask, shape,
-                              est,
-                              prep=(prep.dk, prep.sid) if prep is not None
-                              else None)
+        mom, r, tiles = _score_block(q_kh, q_val, q_mask, kh, vals, mask,
+                                     shape, est,
+                                     prep=(prep.dk, prep.sid)
+                                     if prep is not None else None)
     m = mom[..., 0]
     if batched:
         c_lo = jnp.minimum(q_cmin[:, None], shard.col_min[None, :])
@@ -600,7 +616,7 @@ def _shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax, shard: IndexShard,
         c_lo = jnp.minimum(q_cmin, shard.col_min)
         c_hi = jnp.maximum(q_cmax, shard.col_max)
     lo, hi = K.hoeffding_from_moments(mom, c_lo, c_hi, alpha=alpha)
-    return r, m, hi - lo
+    return r, m, hi - lo, tiles
 
 
 # ----------------------------------------------------------------------------
@@ -696,14 +712,24 @@ def combine_local_topk(s, g, r, m, k: int):
     return take(s), take(g), take(r), take(m)
 
 
+def _rank_outputs(s, r, m, gids, k, axes, host_combine: bool, tiles):
+    """Every plan's rank-stage outputs: the ``(s, g, r, m)`` top-k strips
+    (`_topk_local` under the host combine, else `_topk_gathered`) and the
+    rank kernel's ``[live, total]`` pair tiles summed over the mesh, which
+    the host reads with the strips (`serve._SegmentExec._finish_ranked`)."""
+    top = (_topk_local(s, r, m, gids, k) if host_combine
+           else _topk_gathered(s, r, m, gids, k, axes))
+    return top + (jax.lax.psum(tiles, axes),)
+
+
 def _rank_out_specs(axes, batched: bool, host_combine: bool):
-    """out_specs of the four rank-stage outputs: replicated for the gather
-    combine, sharded along the (per-device) top-k axis for the host
-    combine."""
+    """out_specs of the five `_rank_outputs`: the strips replicated for the
+    gather combine, sharded along the (per-device) top-k axis for the host
+    combine; the pair tiles replicated."""
     if not host_combine:
-        return (P(), P(), P(), P())
+        return (P(),) * 5
     spec = P(None, axes) if batched else P(axes)
-    return (spec,) * 4
+    return (spec,) * 4 + (P(),)
 
 
 def _linear_device_index(axes, sizes):
@@ -787,6 +813,8 @@ def make_scan_fn(mesh, C_total: int, n: int, shape: ShapePolicy,
     ``[B, k_max]`` results bit-identical to B sequential calls, while
     scanning the index once per dispatch. One compiled instance serves
     every estimator × scorer × α × floor and any request k ≤ k_max.
+    Returns `_rank_outputs`: the ranked strips and the rank kernel's
+    ``[live, total]`` pair tiles.
     """
     shape = resolve_kernels(shape, mesh)
     axes = tuple(mesh.axis_names)
@@ -804,16 +832,15 @@ def make_scan_fn(mesh, C_total: int, n: int, shape: ShapePolicy,
             assert q_kh.ndim == 1, q_kh.shape
         prep = rest[0] if with_prep else None
         est, scorer, alpha, floor = _unpack_ops(rest[-1])
-        r, m, ci_len = _shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax,
-                                    shard, shape, est, alpha, prep=prep)
+        r, m, ci_len, tiles = _shard_stats(q_kh, q_val, q_mask, q_cmin,
+                                           q_cmax, shard, shape, est, alpha,
+                                           prep=prep)
         s = score_stats(r, m, ci_len, scorer, floor, axis_names=axes)
         Cl = s.shape[-1]
         lin = _linear_device_index(axes, sizes)
         gids = (jnp.arange(Cl, dtype=jnp.int32)
                 + lin.astype(jnp.int32) * Cl)
-        if host_combine:
-            return _topk_local(s, r, m, gids, k)
-        return _topk_gathered(s, r, m, gids, k, axes)
+        return _rank_outputs(s, r, m, gids, k, axes, host_combine, tiles)
 
     in_specs = _QUERY_SPECS + (_shard_specs(axes),)
     if with_prep:
@@ -1058,8 +1085,8 @@ def make_probe_fn(mesh, C_total: int, n: int, shape: ShapePolicy,
 def _gathered_stats(a, w, values_g, cmin_g, cmax_g, q_cmin, q_cmax,
                     shape: ShapePolicy, est, alpha):
     """(aligned query values, membership, gathered candidate side) → per-
-    candidate (r, m, ci_len), mirroring `_score_block` + `_shard_stats`
-    arithmetic: every per-slot float is the same untouched value the full
+    candidate (r, m, ci_len) and the rank kernel's pair tiles, mirroring
+    `_score_block` + `_shard_stats` arithmetic: every per-slot float is the same untouched value the full
     scan would see, and ``m`` (integer-valued sums of {0,1}) is exactly
     equal. Real-valued scores agree to within a few ulps — XLA may order
     the slot reductions differently across program shapes."""
@@ -1067,18 +1094,16 @@ def _gathered_stats(a, w, values_g, cmin_g, cmax_g, q_cmin, q_cmax,
     mom = jnp.stack([w.sum(-1), a.sum(-1), b.sum(-1), (a * a).sum(-1),
                      (b * b).sum(-1), (a * b).sum(-1)], -1)
 
-    def _ranked(kind):
-        return lambda: K.pearson_from_moments(
-            K.rank_moments(a, b, w, kind, shape.kernels))
-
-    r = _est_select(est, lambda: K.pearson_from_moments(mom),
-                    _ranked("spearman"), _ranked("rin"),
-                    lambda: K.qn_correlation(a, b, w, shape.kernels))
+    r, tiles = _est_select(
+        est, lambda: (K.pearson_from_moments(mom), _no_tiles()),
+        lambda: _ranked(a, b, w, "spearman", shape),
+        lambda: _ranked(a, b, w, "rin", shape),
+        lambda: (K.qn_correlation(a, b, w, shape.kernels), _no_tiles()))
     m = mom[..., 0]
     c_lo = jnp.minimum(q_cmin[..., None], cmin_g)
     c_hi = jnp.maximum(q_cmax[..., None], cmax_g)
     lo, hi = K.hoeffding_from_moments(mom, c_lo, c_hi, alpha=alpha)
-    return r, m, hi - lo
+    return r, m, hi - lo, tiles
 
 
 def _survivor_stats(q_kh, q_val, q_mask, q_cmin, q_cmax, shard: IndexShard,
@@ -1086,8 +1111,9 @@ def _survivor_stats(q_kh, q_val, q_mask, q_cmin, q_cmax, shard: IndexShard,
                     est, alpha):
     """Generic stage-2 body: gather the survivor rows this device owns into
     a masked sub-shard and run the ordinary chunked scorer on it → per-
-    survivor (r, m, ci_len), each ``[.., M]``. Shared by the host-selected
-    `make_pruned_fn` path and the fused inverted plan (`make_inverted_fn`) —
+    survivor (r, m, ci_len), each ``[.., M]``, and the rank kernel's pair
+    tiles. Shared by the host-selected `make_pruned_fn` path and the fused
+    inverted plan (`make_inverted_fn`) —
     identical survivor inputs therefore produce bit-identical stats. Rows
     owned by other devices (and padding beyond ``valid``) stay fully masked:
     they score −inf and the rank combine drops them."""
@@ -1121,7 +1147,8 @@ def make_pruned_fn(mesh, C_total: int, n: int, shape: ShapePolicy, M: int,
     device against the resident index — the host ships only the id vector.
     Each device gathers the survivor rows it owns (others stay masked →
     −inf → dropped by the cross-device top-k combine) and returns the usual
-    (scores, gids, r, m) with **gids already in index space**.
+    (scores, gids, r, m) with **gids already in index space**, plus the
+    rank kernel's pair tiles (`_rank_outputs`).
 
     ``M`` must come from the fixed ladder ``prune_base · 2^i`` (see
     `prune_rung`) so the compile cache stays O(log C); ``M ≥ k_max``
@@ -1199,23 +1226,22 @@ def make_pruned_fn(mesh, C_total: int, n: int, shape: ShapePolicy, M: int,
                           padb(values_g).reshape(mb, cs, n),
                           padb(cmin_g).reshape(mb, cs),
                           padb(cmax_g).reshape(mb, cs))
-                r, m, ci_len = jax.lax.map(one, blocks)
+                r, m, ci_len, tiles = jax.lax.map(one, blocks)
                 mv = lambda x: jnp.moveaxis(x, 0, -2).reshape(
                     (B, M + mpad))[..., :M]
-                r, m, ci_len = mv(r), mv(m), mv(ci_len)
+                r, m, ci_len, tiles = mv(r), mv(m), mv(ci_len), tiles.sum(0)
             else:
-                r, m, ci_len = one((gidx, values_g, cmin_g, cmax_g))
+                r, m, ci_len, tiles = one((gidx, values_g, cmin_g, cmax_g))
         else:
             # generic path (single-query / eq-matrix / Pallas backends):
             # gather the survivor sub-shard and run the ordinary scorer on
             # it (the loc/ok recompute inside folds away under CSE)
-            r, m, ci_len = _survivor_stats(q_kh, q_val, q_mask, q_cmin,
-                                           q_cmax, shard, surv, valid, lin,
-                                           C_local, shape, est, alpha)
+            r, m, ci_len, tiles = _survivor_stats(
+                q_kh, q_val, q_mask, q_cmin, q_cmax, shard, surv, valid,
+                lin, C_local, shape, est, alpha)
         s = score_stats(r, m, ci_len, scorer, floor, axis_names=axes)
-        if host_combine:
-            return _topk_local(s, r, m, surv.astype(jnp.int32), k)
-        return _topk_gathered(s, r, m, surv.astype(jnp.int32), k, axes)
+        return _rank_outputs(s, r, m, surv.astype(jnp.int32), k, axes,
+                             host_combine, tiles)
 
     in_specs = _QUERY_SPECS + (_shard_specs(axes), P(), P())
     if with_prep:
@@ -1300,8 +1326,9 @@ def make_topm_fn(mesh, C_total: int, n: int, shape: ShapePolicy, batch: int,
             values_g = take_rows(shard.values)
             cmin_g = take_rows(shard.col_min)
             cmax_g = take_rows(shard.col_max)
-            r, m, ci_len = _gathered_stats(a, w, values_g, cmin_g, cmax_g,
-                                           q_cmin, q_cmax, shape, est, alpha)
+            r, m, ci_len, tiles = _gathered_stats(a, w, values_g, cmin_g,
+                                                  cmax_g, q_cmin, q_cmax,
+                                                  shape, est, alpha)
         else:
             # per-row candidate sets: score each row's gathered sub-sketches
             # with the single-query kernels (vmapped over the batch)
@@ -1311,10 +1338,11 @@ def make_topm_fn(mesh, C_total: int, n: int, shape: ShapePolicy, batch: int,
             ckh = take_rows(shard.key_hash)
             cvals = take_rows(shard.values)
             cmask = take_rows(shard.mask)
-            mom, r = jax.vmap(
+            mom, r, tiles = jax.vmap(
                 lambda qk1, qv1, qm1, a1, b1, c1: _score_block(
                     qk1, qv1, qm1, a1, b1, c1, shape, est))(
                         q_kh, q_val, q_mask, ckh, cvals, cmask)
+            tiles = tiles.sum(0)
             m = mom[..., 0]
             c_lo = jnp.minimum(q_cmin[:, None], take_rows(shard.col_min))
             c_hi = jnp.maximum(q_cmax[:, None], take_rows(shard.col_max))
@@ -1322,9 +1350,7 @@ def make_topm_fn(mesh, C_total: int, n: int, shape: ShapePolicy, batch: int,
             ci_len = hi - lo
         s = score_stats(r, m, ci_len, scorer, floor, axis_names=axes)
         gids = ids.astype(jnp.int32) + lin.astype(jnp.int32) * C_local
-        if host_combine:
-            return _topk_local(s, r, m, gids, k)
-        return _topk_gathered(s, r, m, gids, k, axes)
+        return _rank_outputs(s, r, m, gids, k, axes, host_combine, tiles)
 
     in_specs = _QUERY_SPECS + (_shard_specs(axes),)
     if with_prep:
@@ -1370,8 +1396,9 @@ def make_inverted_fn(mesh, C_total: int, n: int, shape: ShapePolicy, M: int,
 
     Signature: ``fn(q_kh, q_val, q_mask, q_cmin, q_cmax, shard, keys, cols,
     ops)`` with the postings planes ``keys u32[E]`` / ``cols i32[E]``
-    replicated. Returns the usual ranked ``(s, g, r, m)`` plus the
-    replicated exact survivor-union count ``n_surv i32[]`` — the caller
+    replicated. Returns the usual `_rank_outputs` ``(s, g, r, m, tiles)``
+    plus the replicated exact survivor-union count ``n_surv i32[]`` — the
+    caller
     compares it against the static rung ``M`` to detect overflow and
     re-dispatch on the covering rung
     (`serve._SegmentExec._dispatch_safe_fused`; by `ops.postings_select`,
@@ -1408,16 +1435,13 @@ def make_inverted_fn(mesh, C_total: int, n: int, shape: ShapePolicy, M: int,
         surv, valid, n_surv = K.postings_select(mcols, mcnt, floor, M,
                                                 shape.kernels)
         lin = _linear_device_index(axes, sizes)
-        r, m, ci_len = _survivor_stats(q_kh, q_val, q_mask, q_cmin, q_cmax,
-                                       shard, surv, valid, lin, C_local,
-                                       shape, est, alpha)
+        r, m, ci_len, tiles = _survivor_stats(
+            q_kh, q_val, q_mask, q_cmin, q_cmax, shard, surv, valid, lin,
+            C_local, shape, est, alpha)
         s = score_stats(r, m, ci_len, scorer, floor, axis_names=axes)
-        if host_combine:
-            ranked = _topk_local(s, r, m, surv, k)
-        else:
-            ranked = _topk_gathered(s, r, m, surv, k, axes)
         # probe inputs are replicated, so n_surv is identical on every device
-        return ranked + (n_surv,)
+        return _rank_outputs(s, r, m, surv, k, axes, host_combine,
+                             tiles) + (n_surv,)
 
     in_specs = _QUERY_SPECS + (_shard_specs(axes), P(), P(), P())
     out_specs = _rank_out_specs(axes, True, host_combine) + (P(),)

@@ -117,9 +117,9 @@ def score_shard(q_kh, q_val, q_mask, q_cmin, q_cmax, shard: IndexShard,
     """
     from repro.core.bounds import hoeffding_eligibility_floor
     shape = PL.resolve_kernels(PL.split_config(qcfg)[0])
-    r, m, ci_len = PL._shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax,
-                                   shard, shape, qcfg.estimator, qcfg.alpha,
-                                   prep=prep)
+    r, m, ci_len, _ = PL._shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax,
+                                      shard, shape, qcfg.estimator,
+                                      qcfg.alpha, prep=prep)
     s = PL.score_stats(r, m, ci_len, _static_scorer(qcfg),
                        float(hoeffding_eligibility_floor(qcfg.min_sample)),
                        axis_names=axis_names)
@@ -162,7 +162,7 @@ def make_query_fn(mesh, C_total: int, n: int, qcfg: QueryConfig,
     shape, ops = _split(qcfg)
     fn = PL.make_scan_fn(mesh, C_total, n, shape, batch=batch,
                          with_prep=with_prep)
-    return lambda *args: fn(*args, ops)
+    return lambda *args: fn(*args, ops)[:4]   # the historical outputs
 
 
 def make_stage1_fn(mesh, C_total: int, n: int, qcfg: QueryConfig,
@@ -187,7 +187,7 @@ def make_pruned_query_fn(mesh, C_total: int, n: int, qcfg: QueryConfig,
     shape, ops = _split(qcfg)
     fn = PL.make_pruned_fn(mesh, C_total, n, shape, M, batch=batch,
                            with_prep=with_prep)
-    return lambda *args: fn(*args, ops)
+    return lambda *args: fn(*args, ops)[:4]   # the historical outputs
 
 
 def make_topm_query_fn(mesh, C_total: int, n: int, qcfg: QueryConfig,
@@ -199,7 +199,7 @@ def make_topm_query_fn(mesh, C_total: int, n: int, qcfg: QueryConfig,
     shape, ops = _split(qcfg)
     fn = PL.make_topm_fn(mesh, C_total, n, shape, batch=batch,
                          with_prep=with_prep)
-    return lambda *args: fn(*args, ops)
+    return lambda *args: fn(*args, ops)[:4]   # the historical outputs
 
 
 def query(index_shard: IndexShard, query_sketch, mesh, qcfg: QueryConfig):
@@ -210,4 +210,4 @@ def query(index_shard: IndexShard, query_sketch, mesh, qcfg: QueryConfig):
     shape, ops = _split(qcfg)
     fn = PL.make_scan_fn(mesh, index_shard.num_columns,
                          index_shard.sketch_size, shape)
-    return fn(*qa, index_shard, ops)
+    return fn(*qa, index_shard, ops)[:4]

@@ -279,6 +279,14 @@ def _add_counts(into: Dict[str, int], counts: Dict[str, int]) -> None:
         into[name] = into.get(name, 0) + v
 
 
+def _add_pairs(into: Dict[str, List[int]], pairs) -> None:
+    """Add ``[live, total]`` pairs into ``into`` key by key."""
+    for name, (live, total) in pairs.items():
+        acc = into.setdefault(name, [0, 0])
+        acc[0] += live
+        acc[1] += total
+
+
 class _SegmentExec:
     """Plan executor for one resident (shard, `ShapePolicy`) pair — the
     engine room behind `Server` (one per segment on a live index, exactly
@@ -367,6 +375,11 @@ class _SegmentExec:
         #: The rank estimators' work scales with it; their time is the
         #: device trace's, since they run inside the fused programs
         self._scored_n: Dict[str, int] = {}
+        #: ``{estimator: [live, total]}`` pair tiles of the Pallas
+        #: `rank_moments` (its occupancy skip computes only the live ones,
+        #: DESIGN.md §8), from each plan's trailing output, under
+        #: `_tel_lock`; nothing for pearson, qn or the XLA kernels
+        self._tiles_n: Dict[str, List[int]] = {}
         #: fused inverted-safe dispatch (DESIGN.md §11): last sufficient
         #: survivor rung (adapted per dispatch, guarded by ``_res_lock``)
         #: and the toggle back to the legacy two-dispatch host-selected
@@ -699,13 +712,20 @@ class _SegmentExec:
                 self._scored_n[est] = self._scored_n.get(est, 0) + rows
         return TR.span(_STAGE_SPANS[name])
 
-    def _finish_ranked(self, out):
-        """Block on a rank-stage output and, under the host combine, merge
-        the concatenated per-device local top-ks ``[.., D·kk]`` into the
-        global ``[.., k_max]`` (`plans.combine_local_topk`) — the only
-        cross-shard step of a host-combine dispatch."""
+    def _finish_ranked(self, out, req: PL.Request):
+        """Block on a rank-stage output ``(s, g, r, m, tiles)``, count its
+        rank-kernel pair tiles under ``req``'s estimator (the ``i32[2]``
+        comes back after the same wait as the strips: no sync of its own)
+        and, under the host combine, merge the concatenated per-device
+        local top-ks ``[.., D·kk]`` into the global ``[.., k_max]``
+        (`plans.combine_local_topk`) — the only cross-shard step of a
+        host-combine dispatch."""
         with TR.span("serve.wait"):
-            out = jax.block_until_ready(out)
+            *out, tiles = jax.block_until_ready(out)
+            live, total = (int(t) for t in np.asarray(tiles))
+            if total:
+                with self._tel_lock:
+                    _add_pairs(self._tiles_n, {req.estimator: (live, total)})
             if not self._host_combine:
                 return tuple(np.asarray(o) for o in out)
         with self._stage("combine"):
@@ -744,7 +764,7 @@ class _SegmentExec:
                 rows = B * ndev * PL.topm_width(self.shape, self.C // ndev)
                 with self._stage("topm", req, rows):
                     out = self.topm_fn(B)(*qa, self.shard, *prep_args, ops)
-                    s, g, r, m = self._finish_ranked(out)
+                    s, g, r, m = self._finish_ranked(out, req)
                 g = np.where(np.isfinite(s), g, -1).astype(np.int32)
                 out = (s, g, r, m)
         elif req.prune == "safe":
@@ -752,7 +772,7 @@ class _SegmentExec:
         else:
             with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-                out = self._finish_ranked(out)
+                out = self._finish_ranked(out, req)
         dt = time.perf_counter() - t0
         with self._tel_lock:
             self.dispatch_log.append((B, nq, dt))
@@ -825,7 +845,7 @@ class _SegmentExec:
             # fall back for every survivor count; dispatch the scan direct
             with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-                s, g, r, m = self._finish_ranked(out)
+                s, g, r, m = self._finish_ranked(out, req)
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
             return s, g, r, m
         src = self.source()
@@ -836,9 +856,9 @@ class _SegmentExec:
             with self._stage("fused", req, B * M):
                 out = self.inverted_fused_fn(B, M, src.W)(
                     *qa, self.shard, src._keys_d, src._cols_d, ops)
-                s, g, r, m = self._finish_ranked(out[:4])
+                s, g, r, m = self._finish_ranked(out[:5], req)
                 with TR.span("serve.wait"):
-                    n = int(np.asarray(out[4]))  # replicated union size
+                    n = int(np.asarray(out[5]))  # replicated union size
             need = PL.prune_rung(max(n, self.k_max), self.shape.prune_base,
                                  self.C, ndev)
             if n <= M:
@@ -852,7 +872,7 @@ class _SegmentExec:
                 self._fused_rung = M = need
         with self._stage("scan", req, B * self.C):
             out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-            s, g, r, m = self._finish_ranked(out)
+            s, g, r, m = self._finish_ranked(out, req)
         g = np.where(np.isfinite(s), g, -1).astype(np.int32)
         return s, g, r, m
 
@@ -870,7 +890,7 @@ class _SegmentExec:
         if rung is None:
             with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-                s, g, r, m = self._finish_ranked(out)
+                s, g, r, m = self._finish_ranked(out, req)
             # same id convention as the pruned dispatch below: −inf → −1
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
             return s, g, r, m
@@ -887,7 +907,7 @@ class _SegmentExec:
                                              jnp.asarray(idx),
                                              jnp.asarray(valid), *tab_args,
                                              *prep_args, ops)
-            s, g, r, m = self._finish_ranked(out)
+            s, g, r, m = self._finish_ranked(out, req)
         # stage-2 gids are already index-space; −inf rows (pruned / empty)
         # get id −1 so they can never alias a real column
         g = np.where(np.isfinite(s), g, -1).astype(np.int32)
@@ -915,7 +935,7 @@ class _SegmentExec:
         if rung is None:
             with self._stage("scan", req, B * self.C):
                 out = self.scan_fn(B)(*qa, self.shard, *prep_args, ops)
-                s, g, r, m = self._finish_ranked(out)
+                s, g, r, m = self._finish_ranked(out, req)
             g = np.where(np.isfinite(s), g, -1).astype(np.int32)
             return s, g, r, m
         idx = np.zeros((rung,), np.int32)
@@ -925,7 +945,7 @@ class _SegmentExec:
             out = self.prune_plain_fn(B, rung)(*qa, self.shard,
                                                jnp.asarray(idx),
                                                jnp.asarray(valid), ops)
-            s, g, r, m = self._finish_ranked(out)
+            s, g, r, m = self._finish_ranked(out, req)
         g = np.where(np.isfinite(s), g, -1).astype(np.int32)
         return s, g, r, m
 
@@ -1050,15 +1070,22 @@ class _SegmentExec:
         with self._tel_lock:
             return dict(self._scored_n)
 
+    def rank_tiles(self) -> Dict[str, List[int]]:
+        """Consistent copy of the rank kernel's pair tiles ``{estimator:
+        [live, total]}``."""
+        with self._tel_lock:
+            return {est: list(v) for est, v in self._tiles_n.items()}
+
     def throughput(self) -> dict:
         """Latency/throughput numbers: lifetime totals for queries/qps,
         percentiles over the bounded recent-dispatch window, and the
         per-stage counts (``stages[name] = {count}`` over `_STAGE_NAMES`;
         ``device_dispatches`` sums the device-launch stages — the counter
         the single-dispatch CI gate reads; ``scored_rows`` the candidate
-        rows handed to stage-2 scoring, by estimator). The totals and the
-        log window are read under the telemetry lock, so concurrent
-        dispatches can't tear the percentiles."""
+        rows handed to stage-2 scoring, by estimator; ``rank_tiles`` the
+        `rank_moments` pair tiles computed and covered, by estimator). The
+        totals and the log window are read under the telemetry lock, so
+        concurrent dispatches can't tear the percentiles."""
         with self._tel_lock:
             queries = self._total_queries
             dispatches = self._total_dispatches
@@ -1066,6 +1093,7 @@ class _SegmentExec:
             log = list(self.dispatch_log)
             stage_n = dict(self._stage_n)
             scored = dict(self._scored_n)
+            tiles = {est: list(v) for est, v in self._tiles_n.items()}
         stages = {name: dict(count=n) for name, n in sorted(stage_n.items())}
         devd = sum(stage_n.get(name, 0) for name in _DEVICE_STAGES)
         if not queries:
@@ -1073,7 +1101,7 @@ class _SegmentExec:
                         dispatch_p50_ms=0.0, dispatch_p90_ms=0.0,
                         dispatch_p99_ms=0.0, per_query_ms=0.0,
                         stages=stages, device_dispatches=devd,
-                        scored_rows=scored)
+                        scored_rows=scored, rank_tiles=tiles)
         lat_ms = np.array([t * 1e3 for _, _, t in log])
         return dict(
             queries=queries, dispatches=dispatches,
@@ -1083,7 +1111,8 @@ class _SegmentExec:
             dispatch_p90_ms=float(np.percentile(lat_ms, 90)),
             dispatch_p99_ms=float(np.percentile(lat_ms, 99)),
             per_query_ms=1e3 * total_s / max(queries, 1),
-            stages=stages, device_dispatches=devd, scored_rows=scored)
+            stages=stages, device_dispatches=devd, scored_rows=scored,
+            rank_tiles=tiles)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1181,11 +1210,12 @@ class Server:
         self._q_total = 0
         self._q_seconds = 0.0
         self._retired = dict(dispatches=0)
-        #: per-stage counts and scored rows of retired segment executors
-        #: (folded in by `refresh()` so `throughput()` stays
+        #: per-stage counts, scored rows and rank tiles of retired segment
+        #: executors (folded in by `refresh()` so `throughput()` stays
         #: lifetime-accurate)
         self._retired_stage_n: Dict[str, int] = {}
         self._retired_scored: Dict[str, int] = {}
+        self._retired_tiles: Dict[str, List[int]] = {}
 
         if _is_live(source):
             self._live = source
@@ -1306,6 +1336,7 @@ class Server:
                 for ex in gone:
                     _add_counts(self._retired_stage_n, ex.stage_stats())
                     _add_counts(self._retired_scored, ex.scored_rows())
+                    _add_pairs(self._retired_tiles, ex.rank_tiles())
             self._entries = entries
             self._order = order
             self.names = names
@@ -1546,12 +1577,15 @@ class Server:
                 retired = self._retired["dispatches"]
                 stage_n = dict(self._retired_stage_n)
                 scored = dict(self._retired_scored)
-            # per-stage counts and scored rows across live + retired
-            # segment executors (DESIGN.md §11): every view entry owns a
-            # distinct exec, so the sum is double-count-free
+                tiles = {est: list(v)
+                         for est, v in self._retired_tiles.items()}
+            # per-stage counts, scored rows and rank tiles across live +
+            # retired segment executors (DESIGN.md §11): every view entry
+            # owns a distinct exec, so the sum is double-count-free
             for e in view:
                 _add_counts(stage_n, e.exec.stage_stats())
                 _add_counts(scored, e.exec.scored_rows())
+                _add_pairs(tiles, e.exec.rank_tiles())
             stages = {name: dict(count=n)
                       for name, n in sorted(stage_n.items())}
             out = dict(queries=q_total,
@@ -1564,7 +1598,7 @@ class Server:
                        stages=stages,
                        device_dispatches=sum(stage_n.get(name, 0)
                                              for name in _DEVICE_STAGES),
-                       scored_rows=scored)
+                       scored_rows=scored, rank_tiles=tiles)
         sched = self._scheduler
         if sched is not None:
             out.update(sched.queue_stats())

@@ -159,6 +159,15 @@ def rank_moments(a, b, mask, kind: str = "spearman",
     return _ref.rank_moments(a, b, mask, kind=kind)
 
 
+def rank_tiles(mask, cfg: KernelConfig = XLA):
+    """``i32[2]`` = [pair tiles the Pallas `rank_moments` computes for this
+    mask, pair tiles its grid covers] (the occupancy skip's telemetry,
+    DESIGN.md §8); zeros on the XLA path, which has no tiles."""
+    if cfg.use_pallas:
+        return _rt.rank_tiles(mask.reshape(-1, mask.shape[-1]))
+    return jnp.zeros((2,), jnp.int32)
+
+
 def qn_correlation(a, b, mask, cfg: KernelConfig = XLA):
     """Qn robust correlation per row: a, b, mask f32[..., n] → f32[...].
     Pallas bisects the pairwise-difference bit space in VMEM; the XLA path

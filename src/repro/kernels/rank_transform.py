@@ -21,10 +21,14 @@ Three kernels:
 
 ``rank_moments``
     The fused hot path: per row-block, ranks for ``a`` and ``b`` accumulate
-    in VMEM scratch across the column-block grid and are folded into the six
-    sufficient statistics ``[m, Σrₐ, Σr_b, Σrₐ², Σr_b², Σrₐr_b]`` in the
-    finalize step — the ``[R, n]`` rank arrays never touch HBM, and the
-    output is 6 floats/row instead of n. ``kind="rin"`` applies the rankit
+    in VMEM scratch across the j-tiles of the pair plane and are folded into
+    the six sufficient statistics ``[m, Σrₐ, Σr_b, Σrₐ², Σr_b², Σrₐr_b]``
+    in the finalize step — the ``[R, n]`` rank arrays never touch HBM, and
+    the output is 6 floats/row instead of n. Pair tiles in which no row of
+    the block carries weight are skipped (an occupancy word per row block,
+    scalar-prefetched): they would add exactly 0, so the output is the
+    same bits, and a full scan over a lake in which few columns join the
+    query computes almost none of them. ``kind="rin"`` applies the rankit
     epilogue Φ⁻¹((r − ½)/m) in-register between ranking and the moment
     reduction (`_rankit`: a polynomial erfinv with Python-float
     coefficients — a kernel may capture no array constants, which rules
@@ -184,43 +188,51 @@ def _rankit(r, m):
     return 1.4142135623730951 * jnp.where(w < 5.0, central, tail) * (q - qc)
 
 
-def _moments_kernel(kind, a_ref, b_ref, w_ref, aj_ref, bj_ref, wj_ref,
-                    wrow_ref, out_ref, ra_ref, rb_ref):
-    iblk, jblk = pl.program_id(1), pl.program_id(2)
-    wj = wj_ref[...]                                    # [Br, Bj]
+def _moments_kernel(kind, bi, bj, occ_ref, a_ref, b_ref, w_ref, out_ref,
+                    ra_ref, rb_ref):
+    n = w_ref.shape[-1]
+    ti, tj = n // bi, n // bj
+    word = occ_ref[pl.program_id(0)]
+    # bit t < ti: some row of the block has a live slot in i-tile t; bit
+    # ti + t: the same for j-tile t (`_occupancy`)
+    live = lambda bit: ((word >> bit) & 1) != 0
 
-    def counts(xi, xj):
-        # Σ_j w_j·[x_j < x_i] + ½·Σ_j w_j·[x_j == x_i], this column block
+    def counts(xi, xj, wj):
+        # Σ_j w_j·[x_j < x_i] + ½·Σ_j w_j·[x_j == x_i], this j-tile
         lt = jnp.where(xj[:, None, :] < xi[:, :, None], wj[:, None, :], 0.0)
         eq = jnp.where(xj[:, None, :] == xi[:, :, None], wj[:, None, :], 0.0)
         return jnp.sum(lt + 0.5 * eq, axis=-1)          # [Br, Bi]
 
-    @pl.when(jblk == 0)
-    def _init():
-        ra_ref[...] = jnp.zeros(ra_ref.shape, ra_ref.dtype)
-        rb_ref[...] = jnp.zeros(rb_ref.shape, rb_ref.dtype)
+    out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+    if kind == "rin":
+        msafe = jnp.maximum(jnp.sum(w_ref[...], axis=-1, keepdims=True), 1.0)
 
-    @pl.when((iblk == 0) & (jblk == 0))
-    def _init_moments():
-        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+    for i in range(ti):
+        isl = slice(i * bi, (i + 1) * bi)
 
-    ra_ref[...] += counts(a_ref[...], aj_ref[...])
-    rb_ref[...] += counts(b_ref[...], bj_ref[...])
+        @pl.when(live(i))
+        def _slot_tile():
+            ra_ref[...] = jnp.zeros(ra_ref.shape, ra_ref.dtype)
+            rb_ref[...] = jnp.zeros(rb_ref.shape, rb_ref.dtype)
+            for j in range(tj):
+                jsl = slice(j * bj, (j + 1) * bj)
 
-    @pl.when(jblk == pl.num_programs(2) - 1)
-    def _finalize():
-        w = w_ref[...]                                  # [Br, Bi]
-        ra = (ra_ref[...] + 0.5) * w                    # masked average ranks
-        rb = (rb_ref[...] + 0.5) * w
-        if kind == "rin":
-            m = jnp.sum(wrow_ref[...], axis=-1, keepdims=True)
-            msafe = jnp.maximum(m, 1.0)
-            ra = jnp.where(w > 0, _rankit(ra, msafe), 0.0)
-            rb = jnp.where(w > 0, _rankit(rb, msafe), 0.0)
-        s = lambda x: jnp.sum(x, -1, keepdims=True)
-        out_ref[...] += pack_columns(
-            out_ref.shape,
-            [s(w), s(ra), s(rb), s(ra * ra), s(rb * rb), s(ra * rb)])
+                @pl.when(live(ti + j))
+                def _pair_tile():
+                    wj = w_ref[:, jsl]
+                    ra_ref[...] += counts(a_ref[:, isl], a_ref[:, jsl], wj)
+                    rb_ref[...] += counts(b_ref[:, isl], b_ref[:, jsl], wj)
+
+            w = w_ref[:, isl]                           # [Br, Bi]
+            ra = (ra_ref[...] + 0.5) * w                # masked average ranks
+            rb = (rb_ref[...] + 0.5) * w
+            if kind == "rin":
+                ra = jnp.where(w > 0, _rankit(ra, msafe), 0.0)
+                rb = jnp.where(w > 0, _rankit(rb, msafe), 0.0)
+            s = lambda x: jnp.sum(x, -1, keepdims=True)
+            out_ref[...] += pack_columns(
+                out_ref.shape,
+                [s(w), s(ra), s(rb), s(ra * ra), s(rb * rb), s(ra * rb)])
 
 
 def pair_blocks(block_r: int, n: int, block_n: int = 0) -> tuple:
@@ -230,6 +242,38 @@ def pair_blocks(block_r: int, n: int, block_n: int = 0) -> tuple:
     bj = block_n if block_n > 0 else lane_block(n, 512)
     bi = lane_block(n, max(TILE_BYTES // (4 * block_r * bj), 1))
     return bi, bj
+
+
+def _occupancy(w, block_r: int, bi: int, bj: int):
+    """One int32 word per ``block_r``-row block of ``w`` (rows a multiple of
+    ``block_r``): bit t set when some row of the block has a nonzero weight
+    in i-tile t (width ``bi``), bit ``n/bi + t`` the same for j-tile t
+    (width ``bj``). A pair tile adds exactly 0 to every count unless both
+    of its bits are set."""
+    Rp, n = w.shape
+    ti, tj = n // bi, n // bj
+    assert ti + tj <= 31, (n, bi, bj)
+    rows = jnp.any(w.reshape(Rp // block_r, block_r, n) != 0, axis=1)
+    tiles = jnp.concatenate([rows.reshape(-1, ti, bi).any(-1),
+                             rows.reshape(-1, tj, bj).any(-1)], axis=-1)
+    return jnp.sum(tiles.astype(jnp.int32)
+                   << jnp.arange(ti + tj, dtype=jnp.int32), axis=-1)
+
+
+def rank_tiles(mask, *, block_r: int = 8, block_n: int = 0):
+    """``i32[2]`` = [pair tiles `rank_moments` computes for this mask,
+    pair tiles its grid covers]: the telemetry of the occupancy skip, from
+    the same words the kernel reads (inside one jitted program XLA shares
+    the reduce with the kernel's)."""
+    R, n = mask.shape
+    bi, bj = pair_blocks(block_r, n, block_n)
+    (w,), Rp = _pad_rows([mask.astype(jnp.float32)], R, block_r)
+    occ = _occupancy(w, block_r, bi, bj)
+    ti = n // bi
+    popcount = jax.lax.population_count
+    live = jnp.sum(popcount(occ & ((1 << ti) - 1)) * popcount(occ >> ti))
+    total = (Rp // block_r) * ti * (n // bj)
+    return jnp.stack([live, jnp.int32(total)])
 
 
 @functools.partial(jax.jit,
@@ -243,32 +287,43 @@ def rank_moments(a, b, mask, *, kind: str = "spearman", block_r: int = 8,
     ``kind="rin"`` replaces ranks by the rankit transform before reducing.
     Semantics: :func:`repro.kernels.ref.rank_moments`.
 
-    Grid ``(R / block_r, n / Bi, n / Bj)``: the ranks of one ``[block_r,
-    Bi]`` slot block accumulate in VMEM scratch over the j-blocks and are
-    folded into the row's moment block at the last one; only the [Br, 6]
-    moment block is written back, so HBM output traffic is O(R), not
-    O(R·n), and ranking + moment reduction are one pass over the compare
-    tiles.
+    Grid ``(R / block_r,)``: one step holds a ``[block_r, n]`` row block of
+    a, b and the mask in VMEM and walks its ``[block_r, Bi, Bj]`` pair
+    tiles in a static loop. The ranks of one ``[block_r, Bi]`` slot tile
+    accumulate in VMEM scratch over the j-tiles and are folded into the
+    row block's moments after the last one; only the [Br, 6] moment block
+    is written back, so HBM output traffic is O(R), not O(R·n).
+
+    **Occupancy skip.** A scalar-prefetched word per row block
+    (`_occupancy`, one XLA reduce over the mask) marks the i- and j-tiles
+    in which some row of the block has a nonzero weight. A pair tile whose
+    j-slots all weigh 0 adds exactly +0.0 to every count, and an i-tile
+    whose slots all weigh 0 is multiplied down to 0 by the finalize, so
+    both are skipped (`pl.when`) and the output is bit-identical to
+    computing them. Where few slots join — a full scan over a lake in which
+    few columns share the query's keys — almost every tile is skipped;
+    dense masks pay only the reduce.
     """
     R, n = a.shape
     bi, bj = pair_blocks(block_r, n, block_n)
     assert n % bj == 0 and n % bi == 0, (n, bi, bj)
     w = mask.astype(jnp.float32)
     (a, b, w), Rp = _pad_rows([a, b, w], R, block_r)
+    occ = _occupancy(w, block_r, bi, bj)
 
-    iblock = pl.BlockSpec((block_r, bi), lambda r, i, j: (r, i))
-    jblock = pl.BlockSpec((block_r, bj), lambda r, i, j: (r, j))
+    row = pl.BlockSpec((block_r, n), lambda r, occ: (r, 0))
     out = pl.pallas_call(
-        functools.partial(_moments_kernel, kind),
-        grid=(Rp // block_r, n // bi, n // bj),
-        in_specs=[iblock, iblock, iblock, jblock, jblock, jblock,
-                  pl.BlockSpec((block_r, n), lambda r, i, j: (r, 0))],
-        out_specs=pl.BlockSpec((block_r, 6), lambda r, i, j: (r, 0)),
+        functools.partial(_moments_kernel, kind, bi, bj),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Rp // block_r,),
+            in_specs=[row, row, row],
+            out_specs=pl.BlockSpec((block_r, 6), lambda r, occ: (r, 0)),
+            scratch_shapes=[pltpu.VMEM((block_r, bi), jnp.float32),
+                            pltpu.VMEM((block_r, bi), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((Rp, 6), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_r, bi), jnp.float32),
-                        pltpu.VMEM((block_r, bi), jnp.float32)],
         interpret=interpret,
-    )(a, b, w, a, b, w, w)
+    )(occ, a, b, w)
     return out[:R]
 
 

@@ -90,7 +90,7 @@ def main():
                            n=args.sketch_size)
         qa = IX.query_arrays(qsk)
         t0 = time.time()
-        s, g, r, m = qfn(*qa, shard, ops)
+        s, g, r, m, _ = qfn(*qa, shard, ops)
         jax.block_until_ready(s)
         lat.append((time.time() - t0) * 1000)
         if i == 0:

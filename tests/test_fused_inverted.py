@@ -342,6 +342,38 @@ def test_scored_rows_count_rung_and_scan_width(rng, monkeypatch):
     assert ex.scored_rows() == srv.throughput()["scored_rows"]
 
 
+def test_rank_tiles_count_live_pair_tiles(rng):
+    """``throughput()["rank_tiles"]`` reports, by estimator, the pair tiles
+    the Pallas `rank_moments` computed against those its grid covers.
+    One column of 32 shares the query's keys and the rest are disjoint, so
+    in the scan's ``[B·C, n]`` hit plane only rows ``b·32 + 5`` carry
+    weight: one live 8-row block per bucket row, one pair tile each at
+    n = 32, of the 4·32/8 = 16 the grid covers. Pearson never reaches the
+    kernel and gets no entry."""
+    keys = rng.choice(1 << 20, size=200, replace=False).astype(np.uint32)
+    tables = [Table(keys=(keys if c == 5 else
+                          rng.choice(1 << 20, size=200, replace=False)
+                          .astype(np.uint32) + np.uint32(1 << 21)),
+                    values=rng.standard_normal(200).astype(np.float32),
+                    name=f"t{c}") for c in range(32)]
+    idx = IX.build_index(tables, n=N_SKETCH, pad_to=32)
+    srv = SV.Server(_mesh(), idx,
+                    PL.ShapePolicy(k_max=5,
+                                   kernels=KernelConfig("interpret")),
+                    buckets=(4,), cache=CACHE)
+    sks = SV.build_query_sketches(
+        [keys], [rng.standard_normal(200).astype(np.float32)], n=N_SKETCH)
+    assert srv.throughput()["rank_tiles"] == {}
+    s, g, _, _ = srv.query_batch(sks, request=PL.Request(
+        k=5, prune="off", estimator="spearman"))
+    assert int(np.asarray(g)[0, 0]) == 5
+    live, total = srv.throughput()["rank_tiles"]["spearman"]
+    assert (live, total) == (4, 16) and live < total
+    srv.query_batch(sks, request=PL.Request(k=5, prune="off"))
+    assert srv.throughput()["rank_tiles"] == {"spearman": [4, 16]}
+    assert _exec(srv).rank_tiles() == {"spearman": [4, 16]}
+
+
 def test_stage_telemetry_survives_segment_retirement(rng):
     """Stage totals from retired segment executors fold into the server
     aggregate (same discipline as the retired dispatch counters)."""

@@ -255,8 +255,8 @@ def _static_scan_fn(mesh, shape, req):
     shape = PL.resolve_kernels(shape, mesh)     # as every plan builder does
 
     def local(q_kh, q_val, q_mask, q_cmin, q_cmax, sh):
-        r, m, ci = PL._shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax, sh,
-                                   shape, req.estimator, req.alpha)
+        r, m, ci, _ = PL._shard_stats(q_kh, q_val, q_mask, q_cmin, q_cmax,
+                                      sh, shape, req.estimator, req.alpha)
         s = PL.score_stats(r, m, ci, req.scorer, float(req.min_sample),
                            axis_names=axes)
         Cl = s.shape[-1]

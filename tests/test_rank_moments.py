@@ -80,12 +80,9 @@ def test_rank_moments_matches_unfused_f64_reference(rng, R, n, block_n):
     np.testing.assert_allclose(got_ref, want, rtol=1e-6, atol=1e-6)
 
 
-def test_rank_moments_rin_epilogue(rng):
-    """kind='rin' applies the rankit transform in-register; the result must
-    match an f64 rankit applied to the reference ranks."""
-    a, b, mask = _adversarial(rng)
+def _rin_f64(a, b, mask):
+    """f64 rankit of the reference ranks, reduced to the six moments."""
     aj, bj, mj = (jnp.asarray(x) for x in (a, b, mask))
-    got = np.asarray(RT.rank_moments(aj, bj, mj, kind="rin", interpret=True))
     ra = np.asarray(ref.rank_transform(aj, mj), np.float64)
     rb = np.asarray(ref.rank_transform(bj, mj), np.float64)
     w = np.asarray(mask, np.float64)
@@ -94,10 +91,103 @@ def test_rank_moments_rin_epilogue(rng):
         np.clip((ra - 0.5) / msafe, 1e-6, 1 - 1e-6)), 0.0)
     tb = np.where(w > 0, scipy.special.ndtri(
         np.clip((rb - 0.5) / msafe, 1e-6, 1 - 1e-6)), 0.0)
-    want = _moments_f64(ta, tb, w)
+    return _moments_f64(ta, tb, w)
+
+
+def test_rank_moments_rin_epilogue(rng):
+    """kind='rin' applies the rankit transform in-register; the result must
+    match an f64 rankit applied to the reference ranks."""
+    a, b, mask = _adversarial(rng)
+    aj, bj, mj = (jnp.asarray(x) for x in (a, b, mask))
+    got = np.asarray(RT.rank_moments(aj, bj, mj, kind="rin", interpret=True))
+    want = _rin_f64(a, b, mask)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     got_ref = np.asarray(ref.rank_moments(aj, bj, mj, kind="rin"))
     np.testing.assert_allclose(got_ref, want, rtol=2e-5, atol=2e-5)
+
+
+def _sparse(rng, R, n, pattern):
+    """Masks of a full scan over a lake where few slots join: whole 8-row
+    blocks with no weight beside the patterns the occupancy skip must still
+    compute exactly."""
+    a = (np.round(rng.normal(size=(R, n)) * 4) / 4).astype(np.float32)
+    b = (rng.normal(size=(R, n)) + 0.4 * a).astype(np.float32)
+    mask = np.zeros((R, n), np.float32)
+    if pattern == "empty_blocks":        # block 1 live, its neighbours empty
+        mask[8:16] = rng.random((8, n)) < 0.75
+    elif pattern == "one_slot":          # one live slot a block: one i-tile,
+        for r0 in range(0, R, 8):        # one j-tile
+            if (r0 // 8) % 2 == 0:
+                mask[rng.integers(r0, min(r0 + 8, R)), rng.integers(0, n)] = 1
+    else:                                # "dense_beside_empty"
+        mask[8:16] = 1.0
+        mask[R - 1, : n // 3] = 1.0      # and a partial tail block
+    return a, b, mask
+
+
+@pytest.mark.parametrize("kind", ["spearman", "rin"])
+@pytest.mark.parametrize("pattern",
+                         ["empty_blocks", "one_slot", "dense_beside_empty"])
+@pytest.mark.parametrize("R,n,block_n", [(21, 256, 64), (19, 1024, 0)])
+def test_rank_moments_occupancy_skip_matches_f64_reference(rng, R, n,
+                                                           block_n, pattern,
+                                                           kind):
+    """The occupancy skip is exact: with whole empty 8-row blocks, blocks
+    whose one live slot sits in one i- and one j-tile, and a dense block
+    beside empty ones, at R not a multiple of 8 and tilings with several
+    j-tiles (n = 256, block_n = 64) or several of both (n = 1024: 8 × 2),
+    the kernel matches the f64 unfused oracle, rows without weight come
+    back as exact 0.0, and `ops.rank_moments` over a 3-D lead shape is the
+    same kernel."""
+    a, b, mask = _sparse(rng, R, n, pattern)
+    aj, bj, mj = (jnp.asarray(x) for x in (a, b, mask))
+    moments = lambda a, b, m: np.asarray(RT.rank_moments(
+        jnp.asarray(a), jnp.asarray(b), jnp.asarray(m), kind=kind,
+        block_n=block_n, interpret=True))
+    got = moments(a, b, mask)
+    if kind == "spearman":
+        want = _moments_f64(np.asarray(ref.rank_transform(aj, mj)),
+                            np.asarray(ref.rank_transform(bj, mj)), mask)
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    else:
+        # the rin sums Σ Φ⁻¹(·) cancel towards 0, so their f32 error is
+        # absolute and grows with the slots summed: the rin test's 2e-5
+        # per 32 slots
+        np.testing.assert_allclose(got, _rin_f64(a, b, mask),
+                                   rtol=2e-5, atol=2e-5 * n / 32)
+    # skipping is exact: the same rows, seven to a block beside dense rows
+    # so that every pair tile is computed, give the same bits
+    pos = np.arange(R) // 7 * 8 + np.arange(R) % 7
+    a8, b8 = rng.normal(size=(2, pos[-1] // 8 * 8 + 8, n)).astype(np.float32)
+    m8 = np.ones(a8.shape, np.float32)
+    a8[pos], b8[pos], m8[pos] = a, b, mask
+    np.testing.assert_array_equal(moments(a8, b8, m8)[pos].view(np.int32),
+                                  got.view(np.int32))
+    empty = mask.sum(-1) == 0
+    assert empty.any()
+    assert np.all(got[empty].view(np.int32) == 0)      # +0.0, bit for bit
+    if block_n == 0:
+        got3 = np.asarray(ops.rank_moments(aj[None], bj[None], mj[None],
+                                           kind, INTERP))
+        assert got3.shape == (1, R, 6)
+        np.testing.assert_array_equal(got3[0], got)
+
+
+def test_rank_tiles_counts_the_occupancy(rng):
+    """`rank_tiles` counts the pair tiles the kernel computes: one per
+    block with a single live slot, i-tiles × j-tiles for a dense block,
+    none for an empty one, over the grid's (padded) total."""
+    n = 1024                                 # 8 i-tiles × 2 j-tiles a block
+    mask = np.zeros((35, n), np.float32)     # 5 blocks, the last one padded
+    mask[3, 700] = 1.0                       # block 0: one tile
+    mask[8:16] = 1.0                         # block 1: all 16
+    mask[34, 0] = 1.0                        # block 4: one tile
+    got = np.asarray(RT.rank_tiles(jnp.asarray(mask)))
+    np.testing.assert_array_equal(got, [1 + 16 + 1, 5 * 16])
+    np.testing.assert_array_equal(
+        np.asarray(ops.rank_tiles(jnp.asarray(mask[None]), INTERP)), got)
+    np.testing.assert_array_equal(
+        np.asarray(ops.rank_tiles(jnp.asarray(mask))), [0, 0])
 
 
 def test_rank_moments_feeds_pearson_to_spearman(rng):

@@ -112,5 +112,5 @@ def test_batched_query_serving(rng):
     qfn = lambda *args: sfn(*args, ops)
     for _ in range(3):
         qsk = build_sketch(jnp.asarray(qt.keys), jnp.asarray(qt.values), n=128)
-        s, g, r, m = qfn(*IX.query_arrays(qsk), shard)
+        s, g, r, m, _ = qfn(*IX.query_arrays(qsk), shard)
         assert np.isfinite(np.asarray(s)[np.asarray(m) >= 3]).all()

@@ -107,6 +107,8 @@ def test_postings_select(one_chip, n, W, B):
 @pytest.mark.parametrize("n", SIZES)
 @pytest.mark.parametrize("kind", ("spearman", "rin"))
 def test_rank_moments(one_chip, n, kind):
+    # 4,096 rows, the scan's bucket-8 block (8 × 512 candidates): the
+    # scalar-prefetched occupancy words (one per 8 rows) fill 2 KB of SMEM
     fn = lambda a, b, m: RT.rank_moments(a, b, m, kind=kind)
     _compile(fn, [((32 * 128, n), f32)] * 3, one_chip)
 
